@@ -21,6 +21,13 @@ POLICIES = ("perfect", "inflota", "random")
 PAPER_CHANNEL = ChannelConfig(sigma2=1e-4, p_max=10.0)
 
 
+def device_info() -> Dict[str, object]:
+    """The device a result was measured on, as JAX reports it."""
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices())}
+
+
 def linreg_workers(U: int = 20, k_bar: int = 30, seed: int = 0):
     _, workers, test = tasks_lib.build_task_data(
         "linreg", U=U, k_bar=k_bar, data_seed=seed)

@@ -145,7 +145,7 @@ def round_engine_rows(U: int = 20, D: int = 131072):
 
 
 if __name__ == "__main__":
-    from benchmarks.common import emit
+    from benchmarks.common import device_info, emit
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default="BENCH_kernels.json",
@@ -155,6 +155,6 @@ if __name__ == "__main__":
     emit(rows)
     if args.json:
         with open(args.json, "w") as fh:
-            json.dump({"backend": jax.default_backend(), "rows": rows},
+            json.dump({"device": device_info(), "rows": rows},
                       fh, indent=2)
             fh.write("\n")

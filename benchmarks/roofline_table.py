@@ -11,8 +11,6 @@ import glob
 import json
 import os
 
-from repro.launch.roofline import HBM_BW, ICI_BW, PEAK_FLOPS
-
 RESULTS = os.path.join(os.path.dirname(__file__), "..", "results")
 
 

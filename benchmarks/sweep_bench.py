@@ -56,6 +56,7 @@ import jax
 import numpy as np
 from jax.flatten_util import ravel_pytree
 
+from benchmarks import common
 from repro.core.channel import ChannelConfig
 from repro.core.convergence import LearningConstants
 from repro.core.objectives import Case
@@ -406,7 +407,7 @@ def run(rounds: int = 60, json_path: str | None = None,
                                  else async_rounds,
                                  reps=async_reps)
     if json_path:
-        doc = {"host": platform.node(), "backend": "cpu",
+        doc = {"host": platform.node(), "device": common.device_info(),
                "grid": {"seeds": SEEDS, "policies": list(POLICIES),
                         "channels": [c or "exp_iid" for c in CHANNELS],
                         "rounds": rounds, "U": U, "k_bar": K_BAR,

@@ -62,7 +62,7 @@ ota = None if args.policy == "perfect" else OTAConfig(
 train_step = steps_lib.make_train_step(model, mesh, plan, opt, ota_cfg=ota)
 
 key = jax.random.PRNGKey(0)
-with mesh_lib.activate_mesh(mesh):
+with jax.set_mesh(mesh):
     params = model.init(key, jnp.float32)
     opt_state = opt.init(params)
     stream = synthetic.token_stream(args.batch, args.seq, cfg.vocab_size)

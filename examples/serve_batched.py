@@ -22,7 +22,7 @@ for arch in ("qwen2-0.5b", "rwkv6-7b", "recurrentgemma-2b"):
     rng = np.random.default_rng(0)
     prompt = jnp.asarray(rng.integers(0, cfg.vocab_size, (BATCH, PROMPT)),
                          jnp.int32)
-    with mesh_lib.activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0), jnp.float32)
         out = generate(model, params, prompt, max_seq=PROMPT + GEN,
                        gen=GEN, temperature=0.8)

@@ -4,6 +4,12 @@ A model is a triple of pure functions over a parameter pytree:
     init(key) -> params
     loss(params, x, y) -> scalar
     metrics(params, x, y) -> dict
+
+The models' matmuls run at full f32 precision on every backend
+(``matmul``).  A TPU otherwise runs an f32 matmul as one bfloat16 pass:
+then an ulp of difference in the weights, as between the Pallas and jnp
+rounds, can flip the rounding of an input in the next local update, and
+runs on the chip could not reproduce runs or stores made on the CPU.
 """
 
 from __future__ import annotations
@@ -20,6 +26,11 @@ class TaskModel:
     init: Callable[[Any], Any]
     loss: Callable[[Any, Any, Any], Any]
     metrics: Callable[[Any, Any, Any], Dict[str, Any]]
+
+
+def matmul(a, b):
+    """``a @ b`` at full f32 precision (also in its gradients)."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
 def linreg_model() -> TaskModel:
@@ -61,7 +72,7 @@ def ridge_model(d: int = 8, lam: float = 0.05) -> TaskModel:
         return {"w": jnp.zeros((d,))}
 
     def predict(p, x):
-        return x @ p["w"]
+        return matmul(x, p["w"])
 
     def loss(p, x, y):
         return (jnp.mean((predict(p, x) - y) ** 2)
@@ -90,8 +101,8 @@ def mlp_model(d_in: int = 784, hidden: int = 64,
         }
 
     def logits(p, x):
-        h = jax.nn.relu(x @ p["w1"] + p["b1"])
-        return h @ p["w2"] + p["b2"]
+        h = jax.nn.relu(matmul(x, p["w1"]) + p["b1"])
+        return matmul(h, p["w2"]) + p["b2"]
 
     def loss(p, x, y):
         lg = logits(p, x)

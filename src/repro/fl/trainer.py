@@ -225,6 +225,7 @@ class FLTrainer:
         t0 = time.time()
         compiled = jax.jit(run_fn).lower(key).compile()
         history["compile_s"] = time.time() - t0
+        self.compiled = compiled        # the executable run: its HLO, costs
         out = jax.block_until_ready(compiled(key))
         for k, v in out.items():
             if k != "flat":

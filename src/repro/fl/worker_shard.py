@@ -18,7 +18,8 @@ Two execution modes:
     the experiment axis): values depend only on the logical shard count
     S, never on the device count, so a 4-device experiment-sharded
     sweep of a ``U_shards`` grid stays byte-identical to the 1-device
-    run — the store identity the multi-device test asserts.
+    run on CPU — the store identity the multi-device test asserts (on a
+    TPU, see docs/sweeps.md, Multi-device).
   * mesh (``mesh=worker_mesh()``): ``shard_map`` over the ``'data'``
     FL-worker axis of ``sharding/specs.py`` — each device scans its
     S / n_devices blocks; per-shard search summaries and (D,) transmit
@@ -66,7 +67,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.flatten_util import ravel_pytree
 from jax.sharding import PartitionSpec as P
 
@@ -371,9 +371,9 @@ def build_sharded_engine(task, X, Y, mask, k_i, cfg, params0,
                 lambda v: jax.lax.all_gather(v, mesh_axis, axis=0,
                                              tiled=True), x)
 
-        return shard_map(functools.partial(fn, gather=ag), mesh=mesh,
-                         in_specs=(P(mesh_axis), P()), out_specs=P(),
-                         check_rep=False)(sharded, repl)
+        return jax.shard_map(functools.partial(fn, gather=ag), mesh=mesh,
+                             in_specs=(P(mesh_axis), P()), out_specs=P(),
+                             check_vma=False)(sharded, repl)
 
     def init(flat: jax.Array, key: jax.Array) -> "engine_lib.RoundState":
         carry = model.init_state(jax.random.fold_in(key, 0x636861))

@@ -41,8 +41,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, blk_k: int, seq: int,
 
     def body(i, carry):
         acc, m_i, l_i = carry
-        k = pl.load(k_ref, (pl.ds(i * blk_k, blk_k), slice(None)))
-        v = pl.load(v_ref, (pl.ds(i * blk_k, blk_k), slice(None)))
+        k = k_ref[pl.ds(i * blk_k, blk_k), :]
+        v = v_ref[pl.ds(i * blk_k, blk_k), :]
         s = jnp.dot(q.astype(jnp.float32), k.astype(jnp.float32).T) * scale
         if softcap:
             s = softcap * jnp.tanh(s / softcap)
@@ -74,7 +74,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     softcap: Optional[float] = None,
                     blk_q: int = 128, blk_k: int = 256,
-                    interpret: bool = True):
+                    interpret: bool):
     """q: (B, T, nq, hd); k/v: (B, S, n_kv, hd) -> (B, T, nq, hd).
 
     GQA: query head g of group k attends with kv head k (nq = n_kv · grp).

@@ -13,9 +13,9 @@ keeps the running argmin.  One HBM read per operand, one write per output —
 versus U materialized (U, D) candidate masks in the naive XLA lowering.
 
 ``eta`` / ``numer`` / ``L`` / ``sigma2`` are TRACED operands (eta as a
-per-entry row, the other three as a (3,) SMEM scalar vector), matching
+per-entry row, the other three as one (1, 3) VMEM row), matching
 ``kernels.ota_round``: a jitted caller — or a vmapped sweep cohort that
-varies sigma2 / L per experiment — never recompiles the kernel.
+varies sigma2 / L / numer per experiment — never recompiles the kernel.
 """
 
 from __future__ import annotations
@@ -25,7 +25,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.ota_round import scalar_row
 
 _EPS = 1e-12
 _TOL = 1e-6  # boundary tolerance: candidate k is feasible under b_k^max
@@ -38,9 +39,10 @@ def _kernel(h_ref, wabs_ref, eta_ref, ki_ref, pmax_ref, scal_ref,
     eta = eta_ref[...]                    # (1, blk)
     k_i = ki_ref[...]                     # (U, 1)
     p_max = pmax_ref[...]                 # (U, 1)
-    L = scal_ref[0]                       # (3,) SMEM: [L, sigma2, numer]
-    sigma2 = scal_ref[1]
-    numer = scal_ref[2]
+    scal = scal_ref[...]                  # (1, 3): [L, sigma2, numer]
+    L = scal[:, 0:1]
+    sigma2 = scal[:, 1:2]
+    numer = scal[:, 2:3]
 
     # Candidate matrix, eq. (43)/(81): b_i^max per (worker, entry).  k_i
     # floored: masked workers (k_i = p_max = 0) give candidate 0, not NaN.
@@ -70,7 +72,7 @@ def _kernel(h_ref, wabs_ref, eta_ref, ki_ref, pmax_ref, scal_ref,
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def inflota_search(h, w_abs, k_i, p_max, *, eta, numer,
                    L, sigma2, block_d: int = 1024,
-                   interpret: bool = True):
+                   interpret: bool):
     """Per-entry optimal (b, beta, R) via the Theorem-4 U-point search.
 
     Args:
@@ -84,7 +86,9 @@ def inflota_search(h, w_abs, k_i, p_max, *, eta, numer,
       eta:    TRACED scalar or (D,) Assumption-4 slack.
       numer, L, sigma2: TRACED scalars (numer = case constant C of
         eqs. 35-37, computed by repro.core.objectives.case_numerator);
-        they ride in a (3,) SMEM vector, so none of them recompiles.
+        they ride in a (1, 3) VMEM row, so none of them recompiles.
+      interpret: run the Pallas interpreter (CPU) instead of compiling
+        for the TPU; ``kernels.ops`` picks it from the backend.
 
     Returns: (b (D,), beta (U, D), r (D,)).
     """
@@ -107,9 +111,7 @@ def inflota_search(h, w_abs, k_i, p_max, *, eta, numer,
 
     h_spec = (pl.BlockSpec((U, 1), lambda i: (0, 0)) if rank1
               else pl.BlockSpec((U, block_d), lambda i: (0, i)))
-    scal = jnp.stack([jnp.asarray(L, dt).reshape(()),
-                      jnp.asarray(sigma2, dt).reshape(()),
-                      jnp.asarray(numer, dt).reshape(())])
+    scal = scalar_row(L, sigma2, numer, dt)
     kern = functools.partial(_kernel, U=U)
     b, beta, r = pl.pallas_call(
         kern,
@@ -120,7 +122,7 @@ def inflota_search(h, w_abs, k_i, p_max, *, eta, numer,
             pl.BlockSpec((1, block_d), lambda i: (0, i)),   # eta
             pl.BlockSpec((U, 1), lambda i: (0, 0)),         # k_i
             pl.BlockSpec((U, 1), lambda i: (0, 0)),         # p_max
-            pl.BlockSpec(memory_space=pltpu.SMEM),          # [L,sigma2,numer]
+            pl.BlockSpec((1, 3), lambda i: (0, 0)),         # [L,sigma2,numer]
         ],
         out_specs=[
             pl.BlockSpec((1, block_d), lambda i: (0, i)),

@@ -1,7 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On a real TPU set ``interpret=False`` (or rely on the backend default); on
-CPU the interpreter executes the kernel body in Python for validation.
+The one place that picks interpret mode from the backend: the kernels
+compile for the chip when JAX's default backend is a TPU, and run in the
+Pallas interpreter anywhere else (the CPU tests).  The kernel functions
+themselves take ``interpret`` with no default.
 """
 
 from __future__ import annotations
@@ -25,7 +27,7 @@ def ota_round(w, h, w_abs, eta, noise, k_eff, k_i, p_max, numer,
     ``h`` is the true channel the MAC applies; the optional ``h_est`` is
     the traced CSI estimate the search/transmit inversion uses
     (imperfect-CSI scenarios; None = perfect CSI).  ``L`` / ``sigma2``
-    may be traced scalars (SMEM operands — sweeping them never
+    may be traced scalars (a VMEM row operand — sweeping them never
     recompiles the kernel).
     """
     if interpret is None:

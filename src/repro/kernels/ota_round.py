@@ -31,13 +31,14 @@ dense-``h`` path; (U,)-shaped operands are negligible):
 EVERY scalar the round consumes is a traced operand: ``eta`` (the
 Assumption-4 slack, per entry) and ``numer`` (the case constant C, a
 function of the traced Delta_{t-1}) are arrays, and the learning
-constants ``L`` / ``sigma2`` ride with ``numer`` in a single (3,)
-scalar vector placed in SMEM (``pltpu.SMEM`` — the TPU's scalar memory,
-read before the VPU loop body).  So the whole round engine compiles once
-and runs under ``jax.jit`` / ``jax.lax.scan`` with no per-round
-recompilation or host syncs, and the sweep engine can vmap a cohort that
-varies sigma2 / L per experiment over ONE kernel compilation instead of
-baking each value into its own executable.
+constants ``L`` / ``sigma2`` ride with ``numer`` in a single (1, 3)
+VMEM row.  So the whole round engine compiles once and runs under
+``jax.jit`` / ``jax.lax.scan`` with no per-round recompilation or host
+syncs, and the sweep engine can vmap a cohort that varies sigma2 / L /
+numer per experiment over ONE kernel compilation.  The row is a full-array
+block, so a vmapped cohort batches it to (E, 1, 3) with a (1, 3) block
+per experiment — which the TPU lowering accepts, where a batched SMEM
+vector is refused by its (8, 128) block rule.
 
 Outputs are the per-entry reductions the trainer actually consumes —
 w_hat, b, sum_i K_eff beta (descale denominator), sum_i K_i beta (the
@@ -51,7 +52,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 _EPS = 1e-12
 _TOL = 1e-6  # boundary tolerance: candidate k is feasible under b_k^max
@@ -70,11 +70,10 @@ def _kernel(w_ref, h_ref, hest_ref, wabs_ref, eta_ref, z_ref,
     k_eff = keff_ref[...]     # (U, 1)
     k_i = ki_ref[...]         # (U, 1)
     p_max = pmax_ref[...]     # (U, 1)
-    # (3,) scalar vector in SMEM: traced [L, sigma2, numer] — swept per
-    # experiment without recompiling the kernel
-    L = scal_ref[0]
-    sigma2 = scal_ref[1]
-    numer = scal_ref[2]
+    scal = scal_ref[...]      # (1, 3) traced [L, sigma2, numer]
+    L = scal[:, 0:1]
+    sigma2 = scal[:, 1:2]
+    numer = scal[:, 2:3]
 
     sqrt_p = jnp.sqrt(p_max)
 
@@ -135,15 +134,56 @@ def _shard_tx_kernel(w_ref, h_ref, hest_ref, cw_ref, s_ref, b_ref,
     # amp as there): workers invert the ESTIMATE, the MAC applies true h
     amp = jnp.abs(beta * k_eff * b / h_est * w)
     tx = beta * jnp.sign(w) * jnp.minimum(amp, jnp.sqrt(p_max))
-    y_ref[...] = jnp.sum(tx * h, axis=0, keepdims=True)        # (1, blk)
-    denk_ref[...] = jnp.sum(k_eff * beta, axis=0, keepdims=True)
-    deni_ref[...] = jnp.sum(k_i * beta, axis=0, keepdims=True)
-    sel_ref[...] = jnp.sum(beta, axis=0, keepdims=True)
+    parts = (jnp.sum(tx * h, axis=0, keepdims=True),           # (1, blk)
+             jnp.sum(k_eff * beta, axis=0, keepdims=True),
+             jnp.sum(k_i * beta, axis=0, keepdims=True),
+             jnp.sum(beta, axis=0, keepdims=True))
+    outs = (y_ref, denk_ref, deni_ref, sel_ref)
+    # the worker axis is the inner grid axis: the (1, blk) outputs stay
+    # resident across it and accumulate the worker steps in order
+    first = pl.program_id(1) == 0
+
+    @pl.when(first)
+    def _():
+        for ref, p in zip(outs, parts):
+            ref[...] = p
+
+    @pl.when(jnp.logical_not(first))
+    def _():
+        for ref, p in zip(outs, parts):
+            ref[...] += p
+
+
+def scalar_row(L, sigma2, numer, dt):
+    """The traced [L, sigma2, numer] as one (1, 3) kernel row."""
+    return jnp.stack([jnp.asarray(v, dt).reshape(())
+                      for v in (L, sigma2, numer)])[None, :]
+
+
+# VMEM for the double-buffered inputs of one ``ota_shard_tx`` grid step:
+# the (block_u, block_d) ``w`` tile and its seven (block_u, 1) worker
+# columns, each lane-padded to 128.  Half of v5e's 16 MiB default scoped
+# VMEM, leaving the rest to the kernel's (block_u, block_d) temporaries.
+_SHARD_TX_VMEM = 8 << 20
+
+
+def _shard_tx_tiles(U_b: int, D: int, block_d: int):
+    """(block_u, block_d) of ``ota_shard_tx``: lanes no wider than D needs,
+    and as few worker steps as fit ``_SHARD_TX_VMEM``.  One step
+    (block_u = U_b) whenever the whole block fits."""
+    block_d = min(block_d, -(-D // 128) * 128)
+    max_rows = max(8, _SHARD_TX_VMEM // (4 * 2 * (block_d + 7 * 128))
+                   // 8 * 8)
+    steps = -(-U_b // max_rows)
+    if steps == 1:
+        return U_b, block_d
+    rows = -(-U_b // steps)
+    return -(-rows // 8) * 8, block_d
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def ota_shard_tx(w, h, h_est, cw, s, b, k_eff, k_i, p_max, wmask=None,
-                 *, block_d: int = 1024, interpret: bool = True):
+                 *, block_d: int = 1024, interpret: bool):
     """One worker-shard block's transmit partials, fused in VMEM.
 
     The worker-sharded engine (``fl/worker_shard.py``) decides ``b``
@@ -162,6 +202,15 @@ def ota_shard_tx(w, h, h_est, cw, s, b, k_eff, k_i, p_max, wmask=None,
       k_eff:  (U_b,) descale weights; k_i: (U_b,) true sample counts;
       p_max:  (U_b,) power budgets; wmask: optional (U_b,) real-worker
               mask (None = all real; multiplying by 1.0 is exact).
+      block_d: widest lane tile; narrowed to what D needs.
+      interpret: run the Pallas interpreter (CPU) instead of compiling
+              for the TPU; ``kernels.ops`` picks it from the backend.
+
+    The grid walks (D tiles, worker tiles).  A block too large for VMEM
+    is split into worker tiles padded with inert workers (zero mask and
+    budget, unit gains), whose partials are exactly zero; the tiles'
+    partials are summed in order.  A block that fits takes one worker
+    step, which reduces in the same order as the jnp block ops.
 
     Returns (y_p, denk_p, deni_p, sel_p), each (D,): the block's
     superposition partial (no noise) and the three beta reductions
@@ -172,19 +221,29 @@ def ota_shard_tx(w, h, h_est, cw, s, b, k_eff, k_i, p_max, wmask=None,
     dt = jnp.result_type(w.dtype, jnp.float32)
     if wmask is None:
         wmask = jnp.ones((U_b,), dt)
+    block_u, block_d = _shard_tx_tiles(U_b, D, block_d)
     pad = (-D) % block_d
     if pad:
         w = jnp.pad(w, ((0, 0), (0, pad)))
         s = jnp.pad(s, (0, pad), constant_values=1.0)
         b = jnp.pad(b, (0, pad))
     Dp = D + pad
-    row = pl.BlockSpec((1, block_d), lambda i: (0, i))
-    col = pl.BlockSpec((U_b, 1), lambda i: (0, 0))
+    cols = [jnp.asarray(v, dt)
+            for v in (h, h_est, cw, k_eff, k_i, p_max, wmask)]
+    pad_u = (-U_b) % block_u
+    if pad_u:
+        w = jnp.pad(w, ((0, pad_u), (0, 0)))
+        # h, h_est pad with 1 (the inversion divides by h_est); the
+        # rest with 0, so the mask and budget zero every partial
+        cols = [jnp.pad(v, (0, pad_u), constant_values=float(i < 2))
+                for i, v in enumerate(cols)]
+    row = pl.BlockSpec((1, block_d), lambda i, j: (0, i))
+    col = pl.BlockSpec((block_u, 1), lambda i, j: (j, 0))
     y, denk, deni, sel = pl.pallas_call(
         _shard_tx_kernel,
-        grid=(Dp // block_d,),
+        grid=(Dp // block_d, (U_b + pad_u) // block_u),
         in_specs=[
-            pl.BlockSpec((U_b, block_d), lambda i: (0, i)),   # w
+            pl.BlockSpec((block_u, block_d), lambda i, j: (j, i)),   # w
             col, col, col,                                    # h, h_est, cw
             row, row,                                         # s, b
             col, col, col, col,                    # k_eff, k_i, p_max, wm
@@ -192,18 +251,16 @@ def ota_shard_tx(w, h, h_est, cw, s, b, k_eff, k_i, p_max, wmask=None,
         out_specs=[row, row, row, row],
         out_shape=[jax.ShapeDtypeStruct((1, Dp), dt)] * 4,
         interpret=interpret,
-    )(w.astype(dt), jnp.asarray(h, dt)[:, None],
-      jnp.asarray(h_est, dt)[:, None], jnp.asarray(cw, dt)[:, None],
+    )(w.astype(dt), *[v[:, None] for v in cols[:3]],
       jnp.asarray(s, dt)[None, :], jnp.asarray(b, dt)[None, :],
-      jnp.asarray(k_eff, dt)[:, None], jnp.asarray(k_i, dt)[:, None],
-      jnp.asarray(p_max, dt)[:, None], jnp.asarray(wmask, dt)[:, None])
+      *[v[:, None] for v in cols[3:]])
     return (y[0, :D], denk[0, :D], deni[0, :D], sel[0, :D])
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def ota_round(w, h, w_abs, eta, noise, k_eff, k_i, p_max, numer,
               *, h_est=None, L, sigma2, block_d: int = 1024,
-              interpret: bool = True):
+              interpret: bool):
     """Fused Theorem-4 search + OTA transmit/aggregate, one VMEM pass.
 
     Args:
@@ -226,8 +283,10 @@ def ota_round(w, h, w_abs, eta, noise, k_eff, k_i, p_max, numer,
               (imperfect-CSI scenarios, traced per round).  None =
               perfect CSI.
       L, sigma2: learning constants — TRACED scalars (floats work too):
-              they enter the kernel through a (3,) SMEM scalar vector
-              together with ``numer``, so sweeping them never recompiles.
+              they enter the kernel through a (1, 3) VMEM row together
+              with ``numer``, so sweeping them never recompiles.
+      interpret: run the Pallas interpreter (CPU) instead of compiling
+              for the TPU; ``kernels.ops`` picks it from the backend.
 
     Returns (w_hat, b, den_keff, den_ki, sel), each (D,):
       w_hat:    PS estimate (0 where no worker selected).
@@ -266,11 +325,7 @@ def ota_round(w, h, w_abs, eta, noise, k_eff, k_i, p_max, numer,
 
     row = pl.BlockSpec((1, block_d), lambda i: (0, i))
     col = pl.BlockSpec((U, 1), lambda i: (0, 0))
-    # traced [L, sigma2, numer] live in SMEM (scalar memory): available to
-    # every grid step without occupying VMEM lanes
-    scal = jnp.stack([jnp.asarray(L, dt).reshape(()),
-                      jnp.asarray(sigma2, dt).reshape(()),
-                      jnp.asarray(numer, dt).reshape(())])
+    scal = scalar_row(L, sigma2, numer, dt)
 
     kern = functools.partial(_kernel, U=U)
     what, b, denk, deni, sel = pl.pallas_call(
@@ -286,7 +341,7 @@ def ota_round(w, h, w_abs, eta, noise, k_eff, k_i, p_max, numer,
             col,                                            # k_eff
             col,                                            # k_i
             col,                                            # p_max
-            pl.BlockSpec(memory_space=pltpu.SMEM),          # [L,sigma2,numer]
+            pl.BlockSpec((1, 3), lambda i: (0, 0)),         # [L,sigma2,numer]
         ],
         out_specs=[row, row, row, row, row],
         out_shape=[jax.ShapeDtypeStruct((1, Dp), dt)] * 5,

@@ -52,7 +52,7 @@ def _kernel(w_ref, h_ref, hest_ref, beta_ref, b_ref, z_ref, ki_ref,
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def ota_transmit_aggregate(w, h, beta, b, noise, k_i, p_max,
                            *, h_est=None, block_d: int = 1024,
-                           interpret: bool = True):
+                           interpret: bool):
     """Fused OTA aggregation round.
 
     Args:

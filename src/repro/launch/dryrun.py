@@ -33,6 +33,11 @@ from repro.models.config import INPUT_SHAPES
 from repro.optim import optimizers
 
 
+# The dry-run compiles on host devices and models the production target,
+# so the roofline is priced at the target's peaks, not the host's.
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
 def _mesh_name(mesh) -> str:
     return "x".join(str(mesh.shape[a]) for a in mesh.axis_names)
 
@@ -53,7 +58,7 @@ def build_lowered(arch: str, shape_name: str, mesh, *,
         "fsdp_axes": list(plan.fsdp_axes),
         "params": cfg.param_count(), "active_params": cfg.active_param_count(),
     }
-    with mesh_lib.activate_mesh(mesh):  # in-model sharding constraints
+    with jax.set_mesh(mesh):  # in-model sharding constraints
         if shape.kind == "train":
             opt = optimizers.adamw(1e-4)
             ota_cfg = OTAConfig() if ota else None
@@ -118,7 +123,7 @@ def run_one(arch: str, shape_name: str, mesh, **kw):
                 + mem.output_size_in_bytes - mem.alias_size_in_bytes)
         meta["memory"]["live_bytes"] = int(live)
         meta["memory"]["fits_16gb"] = bool(live < 16e9)
-    rf = roofline.analyze(compiled)
+    rf = roofline.analyze(compiled, TARGET_DEVICE_KIND)
     meta["roofline"] = rf.to_dict()
     if meta.get("model_flops"):
         n_chips = 1

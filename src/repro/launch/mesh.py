@@ -11,24 +11,20 @@ Production target: TPU v5e, 256 chips per pod.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def activate_mesh(mesh):
-    """Context manager activating ``mesh`` for sharding constraints.
-
-    ``jax.set_mesh`` only exists on newer jax; on older releases the Mesh
-    object itself is the context manager for the same resource-env scope.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+def _make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the code shards by
+    ``NamedSharding`` / ``shard_map`` and lets XLA propagate, which the
+    ``Explicit`` axes that ``jax.make_mesh`` defaults to would reject."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_smoke_mesh(data: int | None = None, model: int = 1):
@@ -36,14 +32,14 @@ def make_smoke_mesh(data: int | None = None, model: int = 1):
     n = len(jax.devices())
     if data is None:
         data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _make_mesh((data, model), ("data", "model"))
 
 
 def make_mesh_from_spec(spec: str):
     """'16x16' -> (data, model); '2x16x16' -> (pod, data, model)."""
     dims = tuple(int(x) for x in spec.lower().split("x"))
     if len(dims) == 2:
-        return jax.make_mesh(dims, ("data", "model"))
+        return _make_mesh(dims, ("data", "model"))
     if len(dims) == 3:
-        return jax.make_mesh(dims, ("pod", "data", "model"))
+        return _make_mesh(dims, ("pod", "data", "model"))
     raise ValueError(spec)

@@ -1,10 +1,13 @@
 """Roofline terms from a compiled dry-run artifact.
 
-Hardware model: TPU v5e —
+Hardware model: the ``PEAKS`` table, keyed by ``device_kind`` as JAX
+reports it.  TPU v5e ("TPU v5 lite"; Google Cloud documentation, "TPU
+v5e") —
   peak_flops   197e12 FLOP/s (bf16)
   hbm_bw       819e9  B/s
   ici_bw       50e9   B/s per link (per-device collective payload charged
                against one link; the conservative single-link convention)
+A device that is not in the table is an error, never a default.
 
 ``compiled.cost_analysis()`` counts every while-loop (scan) body ONCE, so
 for the scanned layer stacks it understates per-step work by ~n_layers.
@@ -35,9 +38,27 @@ import dataclasses
 import re
 from typing import Dict, List, Optional, Set, Tuple
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops: float     # FLOP/s, bf16
+    hbm_bw: float    # B/s
+    ici_bw: float    # B/s per link
+
+
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The published peaks of ``device_kind``; raises for unknown kinds."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak table for device_kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -295,15 +316,15 @@ class Roofline:
         return dataclasses.asdict(self)
 
 
-def analyze(compiled) -> Roofline:
-    """Derive the three per-device roofline terms from an executable."""
+def analyze(compiled, device_kind: str) -> Roofline:
+    """Derive the three per-device roofline terms of an executable on
+    ``device_kind`` (a key of ``PEAKS``)."""
+    pk = peaks(device_kind)
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # older jax: one dict per device
-        cost = cost[0] if cost else {}
     an = analyze_hlo(compiled.as_text())
-    compute_s = an.flops / PEAK_FLOPS
-    memory_s = an.hbm_bytes / HBM_BW
-    collective_s = an.collective_bytes / ICI_BW
+    compute_s = an.flops / pk.flops
+    memory_s = an.hbm_bytes / pk.hbm_bw
+    collective_s = an.collective_bytes / pk.ici_bw
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     return Roofline(

@@ -87,7 +87,7 @@ def main() -> None:
         rng.integers(0, cfg.vocab_size, (args.batch, args.prompt_len)),
         jnp.int32)
 
-    with mesh_lib.activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(args.seed), jnp.float32)
         t0 = time.time()
         out = generate(model, params, prompt,

@@ -77,7 +77,7 @@ def main() -> None:
           f"policy={args.policy}")
 
     key = jax.random.PRNGKey(args.seed)
-    with mesh_lib.activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(key, dtype=jnp.float32)
         opt_state = opt.init(params)
         start = 0

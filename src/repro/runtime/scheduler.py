@@ -66,7 +66,6 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from repro.launch import mesh as mesh_lib
 from repro.obs import trace
 from repro.sweep import grid as grid_lib
 from repro.sweep import shard as shard_lib
@@ -508,7 +507,7 @@ class CohortEngine:
         # under another
         self._stack = contextlib.ExitStack()
         if mesh is not None:
-            self._stack.enter_context(mesh_lib.activate_mesh(mesh))
+            self._stack.enter_context(jax.set_mesh(mesh))
         self._pool = ThreadPoolExecutor(
             max_workers=jobs, thread_name_prefix="sweep-dispatch")
 

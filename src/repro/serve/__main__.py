@@ -24,6 +24,7 @@ import signal
 import sys
 
 from repro.obs import flight, logs, trace
+from repro.runtime import compile_cache
 from repro.serve import api as api_lib
 from repro.serve import session as session_lib
 
@@ -117,6 +118,7 @@ def main(argv=None) -> int:
 
     if args.sentinel is not None:
         args.flight = True
+    compile_cache.enable()
     try:
         service = session_lib.SweepService(
             args.store, jobs=jobs, dispatch_ahead=args.dispatch_ahead,

@@ -50,6 +50,7 @@ from typing import Any, List, Tuple
 
 from repro.obs import metrics as metrics_lib
 from repro.obs import trace as trace_lib
+from repro.runtime import compile_cache
 from repro.sweep import shard as shard_lib
 from repro.sweep import store as store_lib
 from repro.sweep.grid import DEFAULTS, SweepSpec, cells, cohorts, run_spec
@@ -421,7 +422,7 @@ def main(argv=None) -> int:
         return 0
 
     service_snap = None
-    if args.submit:
+    if args.submit:             # the client compiles nothing: no JAX here
         from repro.serve import client as client_lib
         try:
             results, service_snap = client_lib.submit_and_wait(
@@ -431,6 +432,7 @@ def main(argv=None) -> int:
             return 2
         store = None
     elif multihost:
+        compile_cache.enable()
         from repro.runtime import multihost as mh
         results = mh.run_spec_multihost(
             spec, store_root=args.store,
@@ -450,6 +452,7 @@ def main(argv=None) -> int:
             return 0
         store = store_lib.SweepStore(args.store)   # shared root store
     else:
+        compile_cache.enable()
         store = store_lib.SweepStore(args.store) if args.store else None
         if store is not None and not args.resume:
             # startup hygiene: tmp debris older than one lease cannot

@@ -15,7 +15,7 @@ Two families of axes vectorize inside a cohort:
     ``eps``, ``rho``, ``L``).  ``eps`` / ``rho`` re-parameterize the
     channel factory per experiment (``ImperfectCSI.eps`` /
     ``GaussMarkovFading.rho`` accept traced scalars); ``sigma2`` / ``L``
-    reach the Pallas kernels as SMEM scalar operands, so even
+    reach the Pallas kernels as traced operands in a VMEM row, so even
     ``backend="pallas"`` cohorts sweep them without recompiling.
   * DATA_AXES — ``U``, ``k_bar``, ``data_seed``.  Cells whose worker
     fleets differ merge into a RAGGED cohort: every cell's worker data is

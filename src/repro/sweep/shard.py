@@ -126,12 +126,18 @@ def dispatch_sharded(batched_fn, batch: Any, mesh=None, *,
     """
     donate_argnums = (0,) if donate and jax.default_backend() != "cpu" \
         else ()
-    fn = jax.jit(batched_fn, donate_argnums=donate_argnums)
     if mesh is None:
-        return fn(batch), None
+        return jax.jit(batched_fn, donate_argnums=donate_argnums)(batch), None
+    # each device runs the vmapped function on its own slice of the
+    # experiment axis: a Pallas (Mosaic) kernel inside cannot be
+    # partitioned by the compiler, and the experiments never interact
+    exp = P(specs.batch_axes(mesh))
+    fn = jax.jit(jax.shard_map(batched_fn, mesh=mesh, in_specs=exp,
+                               out_specs=exp, check_vma=False),
+                 donate_argnums=donate_argnums)
     padded, e = pad_batch(batch, shard_count(mesh))
     placed = shard_batch(padded, mesh)
-    with mesh_lib.activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = fn(placed)
     return out, e
 

@@ -113,3 +113,22 @@ def test_mlp_nonconvex_learns():
         key=jax.random.PRNGKey(2), eval_data=(x[-500:], y[-500:]))
     assert h["accuracy"][-1] > 0.5          # 10 classes, chance = 0.1
     assert h["ce"][-1] < h["ce"][0]
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_mlp_scan_matmuls_full_precision(backend):
+    """Every matmul of a scanned MLP run (local updates, their gradients,
+    eval) is pinned to full f32 precision, whatever the global default:
+    a TPU would otherwise run them as one bfloat16 pass."""
+    counts = partition.sample_counts(3, 8, seed=0)
+    x, y = synthetic.mnist_like(int(np.sum(counts)) + 16, seed=0)
+    workers = partition.partition(x[:-16], y[:-16], counts, seed=0)
+    cfg = FLConfig(rounds=2, lr=0.1, policy="inflota",
+                   case=Case.GD_NONCONVEX, backend=backend, scan=True)
+    tr = FLTrainer(mlp_model(), workers, cfg)
+    tr.run(key=jax.random.PRNGKey(0), eval_data=(x[-16:], y[-16:]))
+    dots = [line for line in tr.compiled.as_text().splitlines()
+            if " dot(" in line]
+    assert len(dots) >= 6                # 2 forward + 2x2 backward, eval
+    assert all("operand_precision={highest,highest}" in line
+               for line in dots), dots

@@ -29,7 +29,7 @@ def test_flash_matches_oracle(B, T, S, nq, nkv, hd, win, cap, dt, bq, bk):
     k = jnp.asarray(rng.normal(size=(B, S, nkv, hd)), dt)
     v = jnp.asarray(rng.normal(size=(B, S, nkv, hd)), dt)
     got = flash_attention(q, k, v, causal=True, window=win, softcap=cap,
-                          blk_q=bq, blk_k=bk)
+                          blk_q=bq, blk_k=bk, interpret=True)
     want = flash_attention_ref(q, k, v, causal=True, window=win,
                                softcap=cap)
     tol = 3e-2 if dt == jnp.bfloat16 else 3e-5
@@ -49,7 +49,8 @@ def test_flash_matches_zoo_attention():
     q = jnp.asarray(rng.normal(size=(B, T, nq, hd)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(B, T, nkv, hd)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(B, T, nkv, hd)), jnp.float32)
-    got = flash_attention(q, k, v, causal=True, blk_q=32, blk_k=32)
+    got = flash_attention(q, k, v, causal=True, blk_q=32, blk_k=32,
+                          interpret=True)
     # zoo math: scores -> mask -> softmax -> PV (attend() internals)
     s = attn._gqa_scores(q, k, None)
     mask = jnp.arange(T)[:, None] >= jnp.arange(T)[None, :]

@@ -86,7 +86,7 @@ def test_train_step_smoke(policy):
     ota = OTAConfig(policy=policy, case=Case.GD_NONCONVEX) if policy \
         else None
     step = steps_lib.make_train_step(model, mesh, plan, opt, ota_cfg=ota)
-    with mesh_lib.activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0), jnp.float32)
         opt_state = opt.init(params)
         batch = registry.make_batch(cfg, ShapeConfig("t", 32, 4, "train"))
@@ -110,7 +110,7 @@ def test_train_step_ota_noise_free_matches_fedavg():
     ota = OTAConfig(policy="perfect", channel=ChannelConfig(sigma2=0.0))
     s_ota = steps_lib.make_train_step(model, mesh, plan, opt, ota_cfg=ota)
     s_ref = steps_lib.make_train_step(model, mesh, plan, opt, ota_cfg=None)
-    with mesh_lib.activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0), jnp.float32)
         batch = registry.make_batch(cfg, ShapeConfig("t", 32, 4, "train"))
         key = jax.random.PRNGKey(1)
@@ -159,3 +159,10 @@ def test_mesh_from_spec():
     m = mesh_lib.make_mesh_from_spec
     with pytest.raises(ValueError):
         m("16")
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    pk = roofline.peaks("TPU v5 lite")
+    assert (pk.flops, pk.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(ValueError, match="no peak table"):
+        roofline.peaks("cpu")

@@ -138,6 +138,86 @@ def test_fused_round_imperfect_csi_matches_oracle():
         assert not np.allclose(np.asarray(out[1]), np.asarray(perfect[1]))
 
 
+def test_fused_round_vmapped_batched_scalars_match_loop():
+    """A sweep cohort's vmapped round: per-experiment L / sigma2 / numer
+    (the (1, 3) scalar row, batched) equal one round per experiment."""
+    rng = np.random.default_rng(5)
+    E, U, D = 3, 6, 300
+    ins = [jnp.stack(x) for x in zip(*[_round_inputs(rng, U, D)
+                                       for _ in range(E)])]
+    numer = jnp.asarray([7.5, 0.3, 42.0], jnp.float32)
+    L = jnp.asarray([2.0, 1.0, 5.0], jnp.float32)
+    sigma2 = jnp.asarray([1e-3, 1e-4, 1e-2], jnp.float32)
+
+    def one(w, h1, w_abs, eta, z, k_eff, k_i, p_max, nu, l, s2):
+        return ops.ota_round(w, h1, w_abs, eta, z, k_eff, k_i, p_max, nu,
+                             L=l, sigma2=s2, block_d=128, interpret=True)
+
+    got = jax.vmap(one)(*ins, numer, L, sigma2)
+    for e in range(E):
+        want = one(*[x[e] for x in ins], numer[e], L[e], sigma2[e])
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a[e]), np.asarray(b),
+                                       rtol=2e-6, atol=2e-6)
+
+
+@pytest.mark.parametrize("u_b", [12, 2500])
+def test_shard_tx_matches_block_ops(u_b):
+    """``ota_shard_tx`` == the jnp block ops of the worker-sharded engine
+    (eq.-44 beta from the rank-1 factorization, Algorithm-1 clipping,
+    the four partial reductions), also when the block is tiled over the
+    worker axis (u_b = 2500 takes three padded worker steps) and when
+    vmapped over experiments."""
+    from repro.core import power
+
+    rng = np.random.default_rng(u_b)
+    D = 3
+
+    def draw():
+        h = jnp.asarray(rng.exponential(size=u_b) + 1e-2, jnp.float32)
+        return dict(
+            w=jnp.asarray(rng.normal(size=(u_b, D)), jnp.float32),
+            h=h, h_est=h * jnp.asarray(rng.uniform(0.9, 1.1, u_b),
+                                       jnp.float32),
+            cw=jnp.asarray(rng.uniform(0.1, 2.0, u_b), jnp.float32),
+            s=jnp.asarray(rng.uniform(0.5, 2.0, D), jnp.float32),
+            b=jnp.asarray(rng.uniform(0.5, 1.5, D), jnp.float32),
+            k_eff=jnp.asarray(rng.integers(5, 20, u_b), jnp.float32),
+            k_i=jnp.asarray(rng.integers(5, 20, u_b), jnp.float32),
+            p_max=jnp.asarray(rng.uniform(0.5, 10.0, u_b), jnp.float32),
+            wmask=jnp.asarray(rng.uniform(size=u_b) < 0.8, jnp.float32))
+
+    def kernel(x):
+        return ops.ota_shard_tx(x["w"], x["h"], x["h_est"], x["cw"],
+                                x["s"], x["b"], x["k_eff"], x["k_i"],
+                                x["p_max"], x["wmask"], interpret=True)
+
+    def reference(x):
+        beta = inflota_core.block_beta(x["b"], x["cw"], x["s"]) \
+            * x["wmask"][:, None]
+        y_terms = power.tx_signal(x["w"], beta, x["k_eff"], x["b"],
+                                  x["h_est"][:, None], x["p_max"]) \
+            * x["h"][:, None]
+        parts = (jnp.sum(y_terms, axis=0),
+                 jnp.sum(x["k_eff"][:, None] * beta, axis=0),
+                 jnp.sum(x["k_i"][:, None] * beta, axis=0),
+                 jnp.sum(beta, axis=0))
+        return parts, jnp.sum(jnp.abs(y_terms), axis=0)
+
+    xs = [draw(), draw()]
+    batched = jax.vmap(kernel)(jax.tree.map(lambda *v: jnp.stack(v), *xs))
+    for e, x in enumerate(xs):
+        want, l1 = reference(x)
+        for got in (kernel(x), [v[e] for v in batched]):
+            # y reassociates over the worker tiles: its error is bounded
+            # by the magnitude of its terms, not of the sum; the
+            # integer-valued beta reductions are exact
+            assert np.all(np.abs(np.asarray(got[0]) - np.asarray(want[0]))
+                          <= 1e-6 * np.asarray(l1))
+            for a, b in zip(got[1:], want[1:]):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_search_kernel_rank1_equals_dense():
     rng = np.random.default_rng(4)
     U, D = 11, 640

@@ -159,7 +159,7 @@ def test_traced_rho_bitexact():
 
 def test_traced_sigma2_pallas_single_cohort():
     """sigma2 swept through the PALLAS backend: the kernels take L /
-    sigma2 as SMEM scalar operands now, so the cohort no longer splits
+    sigma2 as traced operands in a VMEM row, so the cohort no longer splits
     (nor falls back) — and matches sequential pallas runs."""
     spec = SweepSpec(axes={"sigma2": (1e-4, 1e-2)},
                      base={"U": 5, "k_bar": K_BAR, "rounds": 4,

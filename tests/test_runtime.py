@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 from repro.data.tasks import build_task_data
+from repro.runtime import compile_cache
 from repro.runtime import multihost as mh
 from repro.runtime.scheduler import run_cohorts, schedule
 from repro.runtime.writer import Completion, CompletionWriter
@@ -388,8 +389,10 @@ def test_cli_dry_run_prints_schedule(tmp_path, capsys):
     assert "host 0: cohorts" in err and "host 1: cohorts" in err
 
 
-def test_cli_jobs_end_to_end(tmp_path):
+def test_cli_jobs_end_to_end(tmp_path, monkeypatch):
     from repro.sweep.cli import main
+    # main() would turn the persistent compile cache on in this worker
+    monkeypatch.setattr(compile_cache, "enable", lambda: None)
     serial_dir, async_dir = str(tmp_path / "s"), str(tmp_path / "a")
     args = ["--task", "linreg", "--U", str(U), "--k-bar", str(K_BAR),
             "--rounds", "3", "--axis", "seed=0:2",
@@ -398,3 +401,21 @@ def test_cli_jobs_end_to_end(tmp_path):
     assert main(args + ["--store", serial_dir]) == 0
     assert main(args + ["--store", async_dir, "--jobs", "2"]) == 0
     assert _store_files(serial_dir) == _store_files(async_dir)
+
+
+def test_compile_cache_dir(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and the helper sets nothing;
+    otherwise one fixed directory inside the checkout, every call."""
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(root, ".jax_cache")
+        assert compile_cache.enable() == want
+        assert compile_cache.enable() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

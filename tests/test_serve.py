@@ -451,6 +451,35 @@ def test_cli_submit_rejects_local_only_flags():
         cli.main(["--jobs", "fast", "--axis", "seed=0:2"])
 
 
+def test_cli_submit_initializes_no_jax_backend(tmp_path):
+    """``--submit`` is a pure HTTP client: a process that runs it can
+    still hand the chip to another process, because it never starts a
+    JAX backend."""
+    svc = _service(str(tmp_path / "store"))
+    server = api_lib.make_server(svc, "127.0.0.1", 0)
+    threading.Thread(target=server.serve_forever, daemon=True).start()
+    host, port = server.server_address
+    prog = (
+        "from repro.sweep import cli\n"
+        f"rc = cli.main(['--submit', '{host}:{port}', '--task', 'linreg',"
+        f" '--U', '{U}', '--k-bar', '{K_BAR}', '--rounds', '{ROUNDS}',"
+        " '--backend', 'jnp', '--axis', 'seed=0:2', '-q'])\n"
+        "from jax._src import xla_bridge\n"
+        "assert rc == 0, rc\n"
+        "assert not xla_bridge._backends, list(xla_bridge._backends)\n"
+        "print('NO-BACKEND')\n")
+    try:
+        out = subprocess.run([sys.executable, "-c", prog], env=_ENV,
+                             capture_output=True, text=True, timeout=300)
+    finally:
+        server.shutdown()
+        server.server_close()
+        svc.close()
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.startswith("seed,metric,value")   # the served CSV
+    assert "NO-BACKEND" in out.stdout
+
+
 # ----------------------------------------------------------- daemon chaos
 
 def test_killed_daemon_leaves_store_resumable(tmp_path):

@@ -20,6 +20,7 @@ from repro.core.convergence import LearningConstants
 from repro.core.objectives import Case
 from repro.data.tasks import build_task_data
 from repro.fl.trainer import FLConfig, FLTrainer
+from repro.runtime import compile_cache
 from repro.sweep import SweepSpec, SweepStore, cell_hash, run_spec
 from repro.sweep.grid import DEFAULTS, cells, cohorts, result_by
 from repro.sweep.store import canonical_cell, long_rows
@@ -259,8 +260,10 @@ print("SHARD-OK")
 
 # --------------------------------------------------------------------- cli
 
-def test_cli_end_to_end(tmp_path, capsys):
+def test_cli_end_to_end(tmp_path, capsys, monkeypatch):
     from repro.sweep.cli import main, parse_axis
+    # main() would turn the persistent compile cache on in this worker
+    monkeypatch.setattr(compile_cache, "enable", lambda: None)
     assert parse_axis("seed=0:3") == ("seed", [0, 1, 2])
     assert parse_axis("policy=inflota,random") == (
         "policy", ["inflota", "random"])
