@@ -1,0 +1,9 @@
+"""Mean ``cohort.dispatch`` span of the traced window (trace, lower,
+compile or cache read, and enqueue of one cohort on the serial
+``run_spec`` path), from the system's own spans, in ms."""
+
+from bench import program_spans
+
+
+def read(ctx):
+    return program_spans.mean_ms("cohort.dispatch")

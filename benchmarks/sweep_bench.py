@@ -20,7 +20,12 @@ combination) and AFTER (ragged worker padding + traced eps/rho/sigma2/L:
 one compile per shape family).  Both plans execute the same cells;
 ``compile_s`` / ``run_s`` split trace+compile wall time from
 post-compile execution, so the committed numbers show exactly what the
-merge buys.  ``--json`` writes the committed ``BENCH_sweeps.json``.
+merge buys.  The split is read from the system's own spans: a JSONL
+trace recorder (``repro.obs.trace``) in a temporary directory records
+each cohort's ``cohort.dispatch`` (trace, lower, compile, enqueue:
+``compile_s``) and ``cohort.resolve`` (wait for the device, fetch,
+finalize: ``run_s``).  ``--json`` writes the committed
+``BENCH_sweeps.json``.
 
 Section 3 — the ISSUE-5 serial-vs-async runtime comparison.  Two
 workloads, each driven twice over identical cohort computations with
@@ -62,6 +67,7 @@ from repro.core.convergence import LearningConstants
 from repro.core.objectives import Case
 from repro.data.tasks import build_task_data
 from repro.fl.trainer import FLConfig, FLTrainer
+from repro.obs import trace
 from repro.sweep import SweepSpec, SweepStore, run_spec, spec_cache_key
 from repro.sweep.grid import cells, cohorts, run_cohort
 
@@ -121,14 +127,25 @@ def _merge_specs(rounds: int) -> dict[str, SweepSpec]:
 
 
 def _run_plan(spec: SweepSpec, legacy: bool) -> dict[str, float]:
-    """Execute a spec under one cohort plan, timing compile vs run."""
+    """Execute a spec under one cohort plan, timing compile vs run from
+    the cohorts' ``cohort.dispatch`` and ``cohort.resolve`` spans."""
     cl = cells(spec)
     plan = cohorts(cl, legacy=legacy)
-    t: dict[str, float] = {}
-    for co in plan:
-        run_cohort(co, do_eval=False, timings=t)
+    with tempfile.TemporaryDirectory() as d:
+        trace.install(d)
+        try:
+            for order, co in enumerate(plan):
+                run_cohort(co, do_eval=False, order=order)
+        finally:
+            trace.uninstall()
+        events = trace.load_events(d)
+
+    def seconds(name):
+        return sum(e["dur"] for e in events if e["name"] == name) / 1e6
+
     return {"cells": len(cl), "cohorts": len(plan),
-            "compile_s": t["compile_s"], "run_s": t["run_s"]}
+            "compile_s": seconds("cohort.dispatch"),
+            "run_s": seconds("cohort.resolve")}
 
 
 def cohort_merge_rows(rounds: int = 40):

@@ -263,11 +263,18 @@ def build_ota_stage(cfg: FLConfig, k_i: jax.Array, D: int,
             k_eff=k_eff, k_i=k_i, p_max=p_max, numer=numer,
             delta_prev=delta_prev, t=t, wmask=wmask)
 
+        # named scopes label the ops in a profiler trace; metadata only
         if fused is not None:
-            w_hat, b, den_keff, den_ki, sel = fused(W, h_true, noise, ctx)
+            # one kernel searches and transmits: its ops carry both scopes
+            with jax.named_scope("inflota.search"), \
+                    jax.named_scope("ota.transmit"):
+                w_hat, b, den_keff, den_ki, sel = fused(W, h_true, noise,
+                                                        ctx)
         else:
-            dec = policy.decide(kpol, ctx)
-            w_hat = aggregate(W, h_true, h_est, dec.beta, dec.b, noise)
+            with jax.named_scope("inflota.search"):
+                dec = policy.decide(kpol, ctx)
+            with jax.named_scope("ota.transmit"):
+                w_hat = aggregate(W, h_true, h_est, dec.beta, dec.b, noise)
             b = dec.b
             den_keff, den_ki = dec.reductions
             sel = dec.sel
@@ -363,7 +370,8 @@ def build_engine(task, X, Y, mask, k_i, cfg: FLConfig, params0,
 
     def step(state: RoundState, _=None):
         key_next, klocal, kchan, kpol = jax.random.split(state.key, 4)
-        W = local_stage(state.flat, klocal)
+        with jax.named_scope("fl.local_update"):
+            W = local_stage(state.flat, klocal)
         (new_flat, delta, chan_carry, sel, b_mean, a_t, b_t, eta_mean,
          snr) = ota_stage(
             W, state.flat, state.w_prev2, state.delta, state.chan,

@@ -115,8 +115,9 @@ def scan_experiment(task: TaskModel, X, Y, mask, k_i, cfg: FLConfig,
     if collect:
         ex, ey = (jnp.asarray(eval_xy[0]), jnp.asarray(eval_xy[1]))
         idx = jnp.arange(0, cfg.rounds, cfg.eval_every)
-        ms = jax.vmap(
-            lambda f: task.metrics(engine.unravel(f), ex, ey))(flats[idx])
+        with jax.named_scope("fl.eval"):
+            ms = jax.vmap(lambda f: task.metrics(engine.unravel(f), ex,
+                                                 ey))(flats[idx])
         out.update(ms)
     return out
 
@@ -174,8 +175,9 @@ def scan_experiment_block(task: TaskModel, X, Y, mask, k_i, cfg: FLConfig,
         idx = jnp.asarray(np.asarray(eval_offsets, np.int32))
         # vmap over a zero-length axis is fine: a block with no eval
         # rounds still emits every metric key, with a (0,) history
-        ms = jax.vmap(
-            lambda f: task.metrics(engine.unravel(f), ex, ey))(flats[idx])
+        with jax.named_scope("fl.eval"):
+            ms = jax.vmap(lambda f: task.metrics(engine.unravel(f), ex,
+                                                 ey))(flats[idx])
         out.update(ms)
     return state, out
 

@@ -214,39 +214,43 @@ def build_sharded_engine(task, X, Y, mask, k_i, cfg, params0,
         axis.  Every cross-block value is (U,)- or (S, D)-sized.
         """
         if is_inflota:
-            th, cs = gather(jax.vmap(inflota.block_summary)(
-                sharded["cw"], sharded["k_den"]))
-            sstat = repl["s"]
+            # named scopes label the ops in a profiler trace; metadata only
+            with jax.named_scope("inflota.search"):
+                th, cs = gather(jax.vmap(inflota.block_summary)(
+                    sharded["cw"], sharded["k_den"]))
+                sstat = repl["s"]
 
-            def sbody(_, cw_blk):
-                den_blk = inflota.block_den(cw_blk, th, cs)
-                return None, inflota.block_envelope(
-                    cw_blk, den_blk, sstat, policy.constants,
-                    repl["numer_pol"])
+                def sbody(_, cw_blk):
+                    den_blk = inflota.block_den(cw_blk, th, cs)
+                    return None, inflota.block_envelope(
+                        cw_blk, den_blk, sstat, policy.constants,
+                        repl["numer_pol"])
 
-            _, env = jax.lax.scan(sbody, None, sharded["cw"])
-            rmin, kloc, cw_star = gather(env)
-            b, _, _ = inflota.reduce_envelopes(rmin, kloc, cw_star,
-                                               sstat, u_b)
+                _, env = jax.lax.scan(sbody, None, sharded["cw"])
+                rmin, kloc, cw_star = gather(env)
+                b, _, _ = inflota.reduce_envelopes(rmin, kloc, cw_star,
+                                                   sstat, u_b)
         else:
             b = repl["b"]
 
         def tbody(_, xs):
-            Wb = local_block(repl["w_prev"], xs)
-            if is_inflota:
-                if backend is engine_lib.Backend.PALLAS:
-                    from repro.kernels import ops as kops
-                    return None, kops.ota_shard_tx(
-                        Wb, xs["h"], xs["h_est"], xs["cw"], repl["s"], b,
-                        xs["k_eff"], xs["k_i"], xs["p_max"],
-                        wmask=xs.get("wmask"))
-                beta_blk = inflota.block_beta(b, xs["cw"], repl["s"],
-                                              b.dtype)
-                if "wmask" in xs:
-                    beta_blk = beta_blk * xs["wmask"][:, None]
-            else:
-                beta_blk = xs["beta"][:, None]
-            return None, tx_parts(Wb, beta_blk, xs, b)
+            with jax.named_scope("fl.local_update"):
+                Wb = local_block(repl["w_prev"], xs)
+            with jax.named_scope("ota.transmit"):
+                if is_inflota:
+                    if backend is engine_lib.Backend.PALLAS:
+                        from repro.kernels import ops as kops
+                        return None, kops.ota_shard_tx(
+                            Wb, xs["h"], xs["h_est"], xs["cw"], repl["s"],
+                            b, xs["k_eff"], xs["k_i"], xs["p_max"],
+                            wmask=xs.get("wmask"))
+                    beta_blk = inflota.block_beta(b, xs["cw"], repl["s"],
+                                                  b.dtype)
+                    if "wmask" in xs:
+                        beta_blk = beta_blk * xs["wmask"][:, None]
+                else:
+                    beta_blk = xs["beta"][:, None]
+                return None, tx_parts(Wb, beta_blk, xs, b)
 
         _, parts = jax.lax.scan(tbody, None, sharded)
         return gather(parts), b
@@ -288,7 +292,8 @@ def build_sharded_engine(task, X, Y, mask, k_i, cfg, params0,
 
             def fcore(sh, repl, *, gather):
                 def fbody(_, xs):
-                    Wb = local_block(repl["w_prev"], xs)
+                    with jax.named_scope("fl.local_update"):
+                        Wb = local_block(repl["w_prev"], xs)
                     return None, jnp.sum(
                         xs["k_i"][:, None].astype(Wb.dtype) * Wb, axis=0)
                 _, nums = jax.lax.scan(fbody, None, sh)
@@ -352,8 +357,9 @@ def build_sharded_engine(task, X, Y, mask, k_i, cfg, params0,
             repl["b"] = dec.b
 
         parts, b = _dispatch(core, sharded, repl)
-        new_flat, delta, sel, b, a_t, b_t, snr = combine(
-            parts, b, noise, w_prev, state.delta)
+        with jax.named_scope("ota.combine"):
+            new_flat, delta, sel, b, a_t, b_t, snr = combine(
+                parts, b, noise, w_prev, state.delta)
         new_state = engine_lib.RoundState(
             flat=new_flat, w_prev2=w_prev, delta=delta, t=state.t + 1,
             key=key_next, chan=chan_carry)

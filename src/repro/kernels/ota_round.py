@@ -251,6 +251,7 @@ def ota_shard_tx(w, h, h_est, cw, s, b, k_eff, k_i, p_max, wmask=None,
         out_specs=[row, row, row, row],
         out_shape=[jax.ShapeDtypeStruct((1, Dp), dt)] * 4,
         interpret=interpret,
+        name="ota_shard_tx",        # the kernel's name in a profiler trace
     )(w.astype(dt), *[v[:, None] for v in cols[:3]],
       jnp.asarray(s, dt)[None, :], jnp.asarray(b, dt)[None, :],
       *[v[:, None] for v in cols[3:]])
@@ -346,6 +347,7 @@ def ota_round(w, h, w_abs, eta, noise, k_eff, k_i, p_max, numer,
         out_specs=[row, row, row, row, row],
         out_shape=[jax.ShapeDtypeStruct((1, Dp), dt)] * 5,
         interpret=interpret,
+        name="ota_round",           # the kernel's name in a profiler trace
     )(w.astype(dt), h, h_est, w_abs.astype(dt)[None, :], eta[None, :],
       noise.astype(dt)[None, :], jnp.asarray(k_eff, dt)[:, None],
       jnp.asarray(k_i, dt)[:, None], jnp.asarray(p_max, dt)[:, None],

@@ -122,6 +122,7 @@ def ota_transmit_aggregate(w, h, beta, b, noise, k_i, p_max,
         out_specs=pl.BlockSpec((1, block_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, Dp), dt),
         interpret=interpret,
+        name="ota_transmit_aggregate",   # its name in a profiler trace
     )(w.astype(dt), h.astype(dt), h_est.astype(dt), beta.astype(dt),
       b.astype(dt)[None, :], noise.astype(dt)[None, :],
       jnp.asarray(k_i, dt)[:, None], jnp.asarray(p_max, dt)[:, None])

@@ -18,10 +18,21 @@ same-host wall-clock skew against the monotonic clock, and draw flow
 arrows from a cohort claim's original holder to the host that stole it.
 
 The module-level API (:func:`span` / :func:`event`) is what the runtime
-is instrumented with: when no recorder is installed both are no-ops
-(one attribute read), so the traced and untraced code paths execute the
-identical computation — tracing can never change result bytes, only add
-files under ``meta/``.
+is instrumented with.  A record goes to two sinks:
+
+* the installed recorder's JSONL file, as above;
+* a running ``jax.profiler`` capture: the span opens a profiler
+  annotation (``jax.profiler.TraceAnnotation``), so it lands in the
+  ``.xplane.pb`` as a host event on the device trace's clock with its
+  args as stats, and the record is appended to a bounded in-process
+  buffer that :func:`captured` returns.  A harness that profiles a
+  window reads that window's spans from there after the capture stops.
+
+With neither a recorder nor a capture, both are no-ops (one attribute
+read and one ``is_enabled`` call), so the traced and untraced code paths
+execute the identical computation — tracing can never change result
+bytes, only add files under ``meta/``.  Span durations come from
+``time.perf_counter``; ``ts`` is epoch microseconds.
 
 Install via :func:`install` (the CLI's ``--trace`` / the daemon's
 ``--trace``) or the ``REPRO_TRACE`` environment variable (a directory
@@ -37,14 +48,31 @@ import os
 import socket
 import threading
 import time
+from collections import deque
 from typing import (Any, Dict, Iterator, List, Optional, Sequence,
                     Tuple, Union)
 
+try:
+    # the profiler's annotation class (``jax.profiler.TraceAnnotation``
+    # subclasses it and adds nothing); jaxlib is imported without jax, so
+    # ``python -m repro.obs`` stays light
+    from jaxlib._profiler import TraceMe as _Annotation
+    _capturing = _Annotation.is_enabled
+except ImportError:                      # no jaxlib: no profiler to feed
+    _Annotation = None
+
+    def _capturing() -> bool:
+        return False
+
 ENV_VAR = "REPRO_TRACE"
 TRACE_DIRNAME = os.path.join("meta", "trace")
+#: records kept by the in-process capture; the oldest go first
+CAPTURE_MAX = 65536
 
 _lock = threading.Lock()
 _rec: Optional["TraceRecorder"] = None
+_capture_lock = threading.Lock()
+_capture: deque = deque(maxlen=CAPTURE_MAX)
 
 
 def trace_dir_for(store_root: str) -> str:
@@ -116,13 +144,9 @@ class TraceRecorder:
     def event(self, name: str, cat: str = "runtime",
               **args: Any) -> None:
         """Record one instant event (Chrome ``ph: "i"``)."""
-        self._emit({"name": name, "cat": cat, "ph": "i", "s": "t",
-                    "ts": int(time.time() * 1e6), "pid": self.pid,
-                    "tid": threading.get_ident(), "args": args})
+        _event(self, name, cat, args)
 
-    @contextlib.contextmanager
-    def span(self, name: str, cat: str = "runtime",
-             **args: Any) -> Iterator[Dict[str, Any]]:
+    def span(self, name: str, cat: str = "runtime", **args: Any):
         """Record a complete span (``ph: "X"``) around a block.
 
         Yields the mutable ``args`` dict so the block can attach results
@@ -130,19 +154,7 @@ class TraceRecorder:
         span is recorded even when the block raises, with
         ``args["error"]`` naming the exception type.
         """
-        t0 = time.time()
-        try:
-            yield args
-        except BaseException as e:
-            args["error"] = type(e).__name__
-            raise
-        finally:
-            now = time.time()
-            self._emit({"name": name, "cat": cat, "ph": "X",
-                        "ts": int(t0 * 1e6),
-                        "dur": max(0, int((now - t0) * 1e6)),
-                        "pid": self.pid, "tid": threading.get_ident(),
-                        "args": args})
+        return _span(self, name, cat, args)
 
     # ------------------------------------------------------------ lifecycle
     def _flush_locked(self) -> None:
@@ -202,29 +214,92 @@ def enabled() -> bool:
     return _rec is not None
 
 
-def event(name: str, cat: str = "runtime", **args: Any) -> None:
-    """Record an instant event on the installed recorder (no-op when
-    tracing is off)."""
-    rec = _rec
+def _record(rec: Optional["TraceRecorder"], capture: bool,
+            record: Dict[str, Any]) -> None:
     if rec is not None:
-        rec.event(name, cat, **args)
+        rec._emit(record)
+    if capture:
+        with _capture_lock:
+            _capture.append(record)
 
 
-_NULL_ARGS: Dict[str, Any] = {}
+def _event(rec: Optional["TraceRecorder"], name: str, cat: str,
+           args: Dict[str, Any]) -> None:
+    capture = _capturing()
+    if capture:
+        with _Annotation(name, **args):     # a zero-length host event
+            pass
+    _record(rec, capture, {
+        "name": name, "cat": cat, "ph": "i", "s": "t",
+        "ts": int(time.time() * 1e6), "pid": os.getpid(),
+        "tid": threading.get_ident(), "args": args})
 
 
 @contextlib.contextmanager
-def _null_span() -> Iterator[Dict[str, Any]]:
-    yield _NULL_ARGS
+def _span(rec: Optional["TraceRecorder"], name: str, cat: str,
+          args: Dict[str, Any]) -> Iterator[Dict[str, Any]]:
+    # a span that opens inside a profiler capture goes to the capture
+    capture = _capturing()
+    annotation = _Annotation(name, **args) if capture else None
+    opening = set(args) if capture else ()
+    ts = time.time()
+    if annotation is not None:
+        annotation.__enter__()
+    t0 = time.perf_counter()
+    try:
+        yield args
+    except BaseException as e:
+        args["error"] = type(e).__name__
+        raise
+    finally:
+        dur = time.perf_counter() - t0
+        if annotation is not None:
+            late = {k: v for k, v in args.items() if k not in opening}
+            if late:                        # args attached mid-span
+                annotation.set_metadata(**late)
+            annotation.__exit__(None, None, None)
+        _record(rec, capture, {
+            "name": name, "cat": cat, "ph": "X", "ts": int(ts * 1e6),
+            "dur": max(0, int(dur * 1e6)), "pid": os.getpid(),
+            "tid": threading.get_ident(), "args": args})
+
+
+def event(name: str, cat: str = "runtime", **args: Any) -> None:
+    """Record an instant event on the installed recorder and in a
+    running profiler capture (no-op when neither is on)."""
+    rec = _rec
+    if rec is not None or _capturing():
+        _event(rec, name, cat, args)
+
+
+_NULL_ARGS: Dict[str, Any] = {}
+_NULL_SPAN = contextlib.nullcontext(_NULL_ARGS)
 
 
 def span(name: str, cat: str = "runtime", **args: Any):
-    """Span context manager on the installed recorder; a shared no-op
-    when tracing is off (the untraced path stays allocation-free)."""
+    """Span context manager on the installed recorder and a running
+    profiler capture; the shared no-op :data:`_NULL_SPAN` when neither
+    is on (the untraced path stays allocation-free)."""
     rec = _rec
-    if rec is None:
-        return _null_span()
-    return rec.span(name, cat, **args)
+    if rec is None and not _capturing():
+        return _NULL_SPAN
+    return _span(rec, name, cat, args)
+
+
+def captured() -> List[Dict[str, Any]]:
+    """The records of every span and event that opened while a
+    ``jax.profiler`` capture ran in this process (oldest first, at most
+    :data:`CAPTURE_MAX`), in the recorder's record shape: ``name``,
+    ``cat``, ``ph`` (``"X"`` span, ``"i"`` event), ``ts``, ``dur`` (spans;
+    microseconds) and ``args``."""
+    with _capture_lock:
+        return list(_capture)
+
+
+def clear_captured() -> None:
+    """Forget the records :func:`captured` holds."""
+    with _capture_lock:
+        _capture.clear()
 
 
 def flush() -> None:
@@ -240,8 +315,9 @@ def profile(profile_dir: Optional[str]) -> Iterator[None]:
     """Opt-in ``jax.profiler`` capture around a block (``--profile DIR``).
 
     ``None`` is a no-op.  The capture wraps cohort dispatch/execution, so
-    the XLA-level timeline (compile, fusion, device compute) lands next
-    to the lifecycle spans — load the output in TensorBoard or Perfetto.
+    the XLA-level timeline (compile, fusion, device compute) holds the
+    lifecycle spans as host events — load the output in TensorBoard or
+    Perfetto.
     """
     if not profile_dir:
         yield

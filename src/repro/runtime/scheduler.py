@@ -326,12 +326,20 @@ class _Batch:
         return False
 
     # ------------------------------------------------------------ dispatch
-    def dispatch_one(self, entry: ScheduledCohort) -> None:
+    def dispatch_one(self, entry: ScheduledCohort, submitted: float) -> None:
+        """Prepare and dispatch one cohort on a pool thread; ``submitted``
+        is the ``time.perf_counter()`` at which it was handed to the
+        pool."""
         engine = self.engine
+        queued_ms = 1e3 * (time.perf_counter() - submitted)
         if self.stopped or self.error() is not None:
             self.task_finished()
             return
-        if not engine._window.acquire(cancelled=lambda: self.stopped):
+        # waiting: queued_ms for a pool thread, the span for a window slot
+        with trace.span("cohort.wait", cohort=entry.order, batch=self.tag,
+                        queued_ms=queued_ms):
+            acquired = engine._window.acquire(cancelled=lambda: self.stopped)
+        if not acquired:
             self.task_finished()
             return
         if self.stopped:        # failed while we waited for a slot
@@ -555,8 +563,9 @@ class CohortEngine:
                     cohorts=len(entries),
                     cells=sum(len(e.cohort) for e in entries),
                     measured=sum(1 for e in entries if e.measured))
+        submitted = time.perf_counter()
         for e in entries:
-            self._pool.submit(batch.dispatch_one, e)
+            self._pool.submit(batch.dispatch_one, e, submitted)
         return batch
 
     def pending(self) -> int:
@@ -580,7 +589,8 @@ class CohortEngine:
             batch.task_finished()
             return
         try:
-            self._pool.submit(batch.dispatch_one, entry)
+            self._pool.submit(batch.dispatch_one, entry,
+                              time.perf_counter())
         except RuntimeError:            # pool already shut down
             batch.task_finished()
 

@@ -7,7 +7,7 @@ model, task, rounds, case, k_b, backend).  Everything else becomes a
 traced per-experiment operand, so a whole cohort is ONE computation:
 ``fl.trainer.scan_experiment`` lifted over a leading experiment axis with
 ``jax.vmap``, jitted once, and sharded over the device mesh by
-``repro.sweep.shard.run_sharded``.
+``repro.sweep.shard.dispatch_sharded``.
 
 Two families of axes vectorize inside a cohort:
 
@@ -484,8 +484,11 @@ def prepare_cohort(cohort: Cohort, *, do_eval: bool = True,
     """Host-side phase: build task data, split scalars, close the
     per-experiment function.  No device computation is dispatched."""
     ctx = _cohort_context(cohort, do_eval=do_eval, eval_data=eval_data)
+    static = cohort_static_hash(cohort)
 
     def run_one(batch):
+        # the body runs only while JAX traces it: one event per trace
+        obs_trace.event("cohort.trace", cat="sweep", static=static)
         X, Y, mask, k_i, wmask, eval_xy = ctx.data_of(batch)
         return scan_experiment(ctx.task, X, Y, mask, k_i,
                                ctx.cfg_of(batch), batch["key"],
@@ -518,8 +521,10 @@ def prepare_cohort_phases(cohort: Cohort, *, do_eval: bool = True,
     :func:`prepare_cohort`; the computation is split at scan
     boundaries)."""
     ctx = _cohort_context(cohort, do_eval=do_eval, eval_data=eval_data)
+    static = cohort_static_hash(cohort)
 
     def init_one(batch):
+        obs_trace.event("cohort.trace", cat="sweep", static=static)
         X, Y, mask, k_i, wmask, _ = ctx.data_of(batch)
         return scan_experiment_init(ctx.task, X, Y, mask, k_i,
                                     ctx.cfg_of(batch), batch["key"],
@@ -527,6 +532,7 @@ def prepare_cohort_phases(cohort: Cohort, *, do_eval: bool = True,
 
     def block_one(length: int, eval_offsets: Tuple[int, ...]):
         def f(state, batch):
+            obs_trace.event("cohort.trace", cat="sweep", static=static)
             X, Y, mask, k_i, wmask, eval_xy = ctx.data_of(batch)
             return scan_experiment_block(ctx.task, X, Y, mask, k_i,
                                          ctx.cfg_of(batch), state, length,
@@ -710,8 +716,7 @@ def finalize_cohort(cohort: Cohort, out: Dict[str, np.ndarray], *,
 
 
 def run_cohort(cohort: Cohort, *, do_eval: bool = True, tail: int = 10,
-               mesh=None, eval_data=None,
-               timings: Optional[Dict[str, float]] = None
+               mesh=None, eval_data=None, order: int = 0
                ) -> List[Dict[str, Any]]:
     """Execute one cohort as a single vmapped (and mesh-sharded) program.
 
@@ -721,28 +726,24 @@ def run_cohort(cohort: Cohort, *, do_eval: bool = True, tail: int = 10,
     metrics + history).  ``eval_data`` overrides the task's own test
     split (e.g. Fig. 4's fixed held-out set shared across U).
 
-    ``timings`` (single-device only): a dict whose ``compile_s`` /
-    ``run_s`` entries are INCREMENTED with this cohort's trace+compile
-    wall time and its post-compile execution wall time — the numbers
-    ``benchmarks/sweep_bench.py`` commits for the cohort-merge
-    before/after comparison.
+    The three phases are the async runtime's, traced under its span
+    names: ``cohort.prepare`` (host data), ``cohort.dispatch`` (trace,
+    lower, compile or cache read, enqueue) and ``cohort.resolve`` (wait
+    for the device, fetch, finalize).  ``order`` is the cohort's
+    position in its plan, which the spans carry as ``cohort``.
     """
-    prep = prepare_cohort(cohort, do_eval=do_eval, eval_data=eval_data)
-    if timings is not None and mesh is None:
-        import time
-        fn = jax.jit(jax.vmap(prep.run_one))
-        t0 = time.time()
-        compiled = fn.lower(prep.batch).compile()
-        t1 = time.time()
-        out = jax.block_until_ready(compiled(prep.batch))
-        t2 = time.time()
-        timings["compile_s"] = timings.get("compile_s", 0.0) + (t1 - t0)
-        timings["run_s"] = timings.get("run_s", 0.0) + (t2 - t1)
-    else:
-        out = shard_lib.run_sharded(jax.vmap(prep.run_one), prep.batch,
-                                    mesh)
-    out = {k: np.asarray(v) for k, v in out.items()}
-    return finalize_cohort(cohort, out, tail=tail)
+    with obs_trace.span("cohort.prepare", cat="sweep", cohort=order,
+                        cells=len(cohort)):
+        prep = prepare_cohort(cohort, do_eval=do_eval, eval_data=eval_data)
+    with obs_trace.span("cohort.dispatch", cat="sweep", cohort=order,
+                        cells=len(cohort)):
+        out, e = shard_lib.dispatch_sharded(jax.vmap(prep.run_one),
+                                            prep.batch, mesh)
+    with obs_trace.span("cohort.resolve", cat="sweep", cohort=order,
+                        cells=len(cohort)):
+        host = shard_lib.resolve(out, e)
+        host = {k: np.asarray(v) for k, v in host.items()}
+        return finalize_cohort(cohort, host, tail=tail)
 
 
 def spec_cache_key(spec: SweepSpec) -> Dict[str, Any]:
@@ -773,7 +774,6 @@ def runtime_gc(store_root: str) -> None:
 
 def run_spec(spec: SweepSpec, *, store: Optional[store_lib.SweepStore] = None,
              mesh=None, eval_data=None, verbose: bool = False,
-             timings: Optional[Dict[str, float]] = None,
              jobs: Union[int, str] = 1,
              dispatch_ahead: Optional[int] = None,
              resume: bool = False, checkpoint_every: Optional[int] = None,
@@ -833,10 +833,6 @@ def run_spec(spec: SweepSpec, *, store: Optional[store_lib.SweepStore] = None,
         # cell, so cached entries would be poisoned for ordinary runs
         raise ValueError("store and eval_data are mutually exclusive; "
                          "run eval-override sweeps uncached")
-    if jobs > 1 and timings is not None:
-        raise ValueError("timings= requires the serial path (jobs=1): "
-                         "concurrent compile/run walls overlap and cannot "
-                         "be attributed per phase")
     if checkpoint_every is not None:
         if store is None:
             raise ValueError("checkpoint_every requires a store (the "
@@ -940,7 +936,7 @@ def run_spec(spec: SweepSpec, *, store: Optional[store_lib.SweepStore] = None,
                     tail=spec.tail, eval_data=eval_data, verbose=verbose)
             return run_cohort(cohort, do_eval=spec.eval, tail=spec.tail,
                               mesh=mesh, eval_data=eval_data,
-                              timings=timings)
+                              order=order - 1)
 
         # schedule-time prediction (measured walls only): graded against
         # the realized wall below, same contract as the async scheduler

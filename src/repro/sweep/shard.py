@@ -147,16 +147,3 @@ def resolve(out: Any, e: Optional[int]) -> Any:
     (unpadding back to the original experiment count when sharded)."""
     out = jax.device_get(out)
     return out if e is None else unpad(out, e)
-
-
-def run_sharded(batched_fn, batch: Any, mesh=None) -> Any:
-    """Run ``batched_fn`` (vmapped over the leading axis) with the
-    experiment axis sharded across ``mesh``.
-
-    Handles pad -> place -> jit -> unpad; the single-device path is just
-    ``jit(batched_fn)(batch)``.
-    """
-    if mesh is None:
-        return jax.jit(batched_fn)(batch)
-    out, e = dispatch_sharded(batched_fn, batch, mesh)
-    return resolve(out, e)

@@ -7,6 +7,7 @@ exclude.  The registry is the single source for every metrics surface,
 so its JSON snapshot and its Prometheus text must always agree.
 """
 
+import glob
 import io
 import json
 import os
@@ -18,6 +19,8 @@ import pytest
 from repro.obs import logs, metrics, report, trace
 from repro.obs.trace import TraceRecorder
 from repro.sweep import SweepSpec, SweepStore, run_spec
+from repro.sweep.grid import (cells, cohort_static_hash, cohorts,
+                              prepare_cohort)
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -28,6 +31,7 @@ def _obs_clean():
     into other tests (or between tests here)."""
     yield
     trace.uninstall()
+    trace.clear_captured()
     logs.configure(json_mode=False, stream=None)
 
 
@@ -100,6 +104,127 @@ def test_install_from_env(tmp_path, monkeypatch):
     trace.event("hello", cat="test")
     trace.uninstall()
     assert [e["name"] for e in trace.load_events(d)] == ["hello"]
+
+
+# --------------------------------------------------------- profiler bridge
+
+def _host_events(trace_dir, names):
+    """{name: [stats dict, ...]} of the host-plane events in the newest
+    ``.xplane.pb`` under ``trace_dir``."""
+    (path,) = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    out = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in names:
+                    out.setdefault(ev.name, []).append(dict(ev.stats))
+    return out
+
+
+def test_span_in_a_profiler_capture_reaches_xplane_and_capture(tmp_path):
+    trace.uninstall()
+    trace.clear_captured()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.span("cohort.prepare", cat="test", cells=3) as args:
+            args["late"] = 7                # attached mid-span
+        trace.event("cohort.trace", cat="test", static="abc")
+    finally:
+        jax.profiler.stop_trace()
+    with trace.span("after.stop", cat="test"):
+        pass                                # no capture, no recorder
+
+    got = _host_events(tmp_path, {"cohort.prepare", "cohort.trace"})
+    assert got["cohort.prepare"] == [{"cells": 3, "late": 7}]
+    assert got["cohort.trace"] == [{"static": "abc"}]
+    span, event = trace.captured()
+    assert (span["name"], span["ph"], span["args"]) == (
+        "cohort.prepare", "X", {"cells": 3, "late": 7})
+    assert isinstance(span["dur"], int) and span["dur"] >= 0
+    assert (event["name"], event["ph"], event["args"]) == (
+        "cohort.trace", "i", {"static": "abc"})
+
+
+def test_untraced_span_is_the_shared_null_span():
+    trace.uninstall()
+    trace.clear_captured()
+    first = trace.span("a", cells=1)
+    assert first is trace.span("b") is trace._NULL_SPAN
+    with first as args:
+        args["k"] = 1
+    trace.event("c")
+    assert trace.captured() == []
+
+
+def _names(trace_dir, name):
+    return [e for e in trace.load_events(trace_dir) if e["name"] == name]
+
+
+def test_cohort_trace_fires_once_per_trace(tmp_path):
+    spec = SweepSpec(axes={"seed": (0, 1)},
+                     base={"task": "linreg", "U": U, "k_bar": K_BAR,
+                           "rounds": ROUNDS})
+    (co,) = cohorts(cells(spec))
+    prep = prepare_cohort(co, do_eval=False)
+    d = str(tmp_path / "t")
+    trace.install(d)
+    fn = jax.jit(jax.vmap(prep.run_one))
+    jax.block_until_ready(fn(prep.batch))
+    jax.block_until_ready(fn(prep.batch))         # a cache hit: no trace
+    jax.block_until_ready(jax.jit(jax.vmap(prep.run_one))(prep.batch))
+    trace.uninstall()
+    evs = _names(d, "cohort.trace")
+    assert len(evs) == 2                          # two jitted functions
+    assert {e["args"]["static"] for e in evs} == {cohort_static_hash(co)}
+
+
+def _inside(inner, outer):
+    return (outer["ts"] <= inner["ts"]
+            and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"])
+
+
+def test_serial_run_spec_traces_each_cohort_phase(tmp_path):
+    spec = SweepSpec(axes={"policy": ("inflota", "random"), "seed": (0, 1)},
+                     base={"task": "linreg", "U": U, "k_bar": K_BAR,
+                           "rounds": ROUNDS})
+    d = str(tmp_path / "t")
+    trace.install(d)
+    run_spec(spec)
+    trace.uninstall()
+    runs = _names(d, "cohort.run")
+    assert sorted(r["args"]["cohort"] for r in runs) == [0, 1]
+    for phase in ("cohort.prepare", "cohort.dispatch", "cohort.resolve"):
+        spans = _names(d, phase)
+        assert sorted(s["args"]["cohort"] for s in spans) == [0, 1]
+        for s in spans:
+            (run,) = [r for r in runs
+                      if r["args"]["cohort"] == s["args"]["cohort"]]
+            assert _inside(s, run)
+    assert len(_names(d, "cohort.trace")) == 2
+
+
+def test_engine_records_cohort_wait(tmp_path):
+    spec = SweepSpec(axes={"policy": ("inflota", "random"), "seed": (0, 1)},
+                     base={"task": "linreg", "U": U, "k_bar": K_BAR,
+                           "rounds": ROUNDS})
+    d = str(tmp_path / "t")
+    trace.install(d)
+    run_spec(spec, jobs=2, dispatch_ahead=0)
+    trace.uninstall()
+    waits = _names(d, "cohort.wait")
+    assert sorted(w["args"]["cohort"] for w in waits) == [0, 1]
+    for w in waits:
+        assert w["args"]["queued_ms"] >= 0.0 and w["dur"] >= 0
+    prepares = _names(d, "cohort.prepare")
+    # each cohort waits before its preparation starts (a millisecond of
+    # room: ts is the wall clock, dur the performance counter)
+    for w in waits:
+        (p,) = [p for p in prepares
+                if p["args"]["cohort"] == w["args"]["cohort"]]
+        assert w["ts"] + w["dur"] <= p["ts"] + 1000
 
 
 # ------------------------------------------------------------ chrome export
