@@ -16,9 +16,11 @@ Two entry points share one machinery:
 ``run_cohorts`` executes a list of sweep cohorts through three
 overlapping stages instead of a serial loop:
 
-  dispatch (jobs threads)   prepare_cohort -> trace/compile -> async
-                            device dispatch (donated batches); the jit
-                            call returns while the computation runs
+  dispatch (jobs threads)   prepare_cohort -> dispatch_cohort: the
+                            cached jitted program (trace/compile only
+                            on a cache miss), async device dispatch
+                            (donated batches); the jit call returns
+                            while the computation runs
   device                    up to ``jobs + dispatch_ahead`` cohorts in
                             flight at once (window semaphore)
   writer (1 thread)         device_get + finalize + sink (store writes)
@@ -389,10 +391,10 @@ class _Batch:
                         eval_data=self.eval_data)
                 with trace.span("cohort.dispatch", cohort=entry.order,
                                 batch=self.tag, cells=len(co),
-                                cost=entry.cost):
-                    out, e = shard_lib.dispatch_sharded(
-                        jax.vmap(prep.run_one), prep.batch,
-                        engine._mesh, donate=True)
+                                cost=entry.cost) as args:
+                    out, e, args["hit"] = grid_lib.dispatch_cohort(
+                        prep, engine._mesh, donate=True,
+                        registry=engine.registry)
 
                 def resolve_fn(out=out, e=e, co=co, entry=entry, t0=t0):
                     if self.stopped:

@@ -7,7 +7,9 @@ model, task, rounds, case, k_b, backend).  Everything else becomes a
 traced per-experiment operand, so a whole cohort is ONE computation:
 ``fl.trainer.scan_experiment`` lifted over a leading experiment axis with
 ``jax.vmap``, jitted once, and sharded over the device mesh by
-``repro.sweep.shard.dispatch_sharded``.
+``repro.sweep.shard``.  The jitted program is kept per trace key
+(``prepare_cohort``), so a later cohort that differs only in its seeds
+or varying scalar values skips trace, lowering and the data build.
 
 Two families of axes vectorize inside a cohort:
 
@@ -42,10 +44,13 @@ full U x eps x sigma2 grid is ONE compile per backend instead of one per
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import os
 import shutil
 import sys
+import threading
 import time
+from collections import OrderedDict
 from typing import (Any, Dict, List, Mapping, Optional, Sequence, Tuple,
                     Union)
 
@@ -54,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import channel as chan_lib
+from repro.core import selection as sel_lib
 from repro.core.channel import ChannelConfig
 from repro.core.convergence import LearningConstants
 from repro.core.objectives import Case
@@ -319,23 +325,21 @@ def _pad_worker_axis(a: jnp.ndarray, u_max: int) -> jnp.ndarray:
     return jnp.pad(a, pad)
 
 
-def _ragged_batch(cohort: Cohort, built: Dict[Tuple, Any], do_eval: bool,
-                  eval_override
-                  ) -> Tuple[Dict[str, jnp.ndarray],
-                             Dict[str, jnp.ndarray], bool]:
-    """Deduplicated per-experiment data for a ragged cohort.
+def _ragged_uniques(cohort: Cohort, built: Dict[Tuple, Any], do_eval: bool,
+                    eval_override
+                    ) -> Tuple[Dict[str, jnp.ndarray], bool]:
+    """Deduplicated worker data for a ragged cohort.
 
     Every UNIQUE dataset (``data_keys``: task x U x k_bar x data_seed) is
     padded to the cohort-wide (U_max, K_max) exactly once and stacked
     into ``uniques`` (leading axis = unique dataset, NOT experiment);
-    each experiment carries only an i32 index ``didx`` into that stack.
-    ``run_one`` gathers its cell's block by index, so an 8-seed x 3-U
-    cohort holds 3 padded copies of the worker data instead of 24 — the
-    gather returns the identical padded arrays, so results are unchanged
-    bit-for-bit.
+    each experiment carries only an i32 index ``didx`` into that stack
+    (:func:`_cohort_batch`).  ``run_one`` gathers its cell's block by
+    index, so an 8-seed x 3-U cohort holds 3 padded copies of the worker
+    data instead of 24 — the gather returns the identical padded arrays,
+    so results are unchanged bit-for-bit.
 
-    Returns (batch, uniques, batch_eval): ``batch`` leaves have a leading
-    experiment axis (vmapped / sharded), ``uniques`` are closed over by
+    Returns (uniques, batch_eval): ``uniques`` are closed over by
     ``run_one`` (replicated).  Per-key test splits dedup the same way
     unless an ``eval_override`` supplies one shared set.
     """
@@ -367,33 +371,27 @@ def _ragged_batch(cohort: Cohort, built: Dict[Tuple, Any], do_eval: bool,
 
     uniques = {"X": stack(0), "Y": stack(1), "mask": stack(2),
                "k_i": stack(3), "wmask": stack(4)}
-    key_pos = {k: i for i, k in enumerate(keys)}
-    batch = {"didx": jnp.asarray(
-        [key_pos[_data_key(c)] for c in cohort.cells], jnp.int32)}
     batch_eval = do_eval and eval_override is None
     if batch_eval:
         uniques["ex"] = jnp.stack(
             [jnp.asarray(per_key[k][5][0]) for k in keys])
         uniques["ey"] = jnp.stack(
             [jnp.asarray(per_key[k][5][1]) for k in keys])
-    return batch, uniques, batch_eval
+    return uniques, batch_eval
 
 
-@dataclasses.dataclass
-class PreparedCohort:
-    """A cohort with its data built and its computation closed over.
-
-    ``jax.vmap(run_one)`` applied to ``batch`` IS the cohort's whole
-    computation; the split from :func:`run_cohort` exists so the async
-    runtime (``repro.runtime``) can stage host-side preparation, device
-    dispatch, and result finalization on different threads while the
-    serial path composes the same three pieces in order — per-cell
-    results are identical by construction.
-    """
-
-    cohort: Cohort
-    run_one: Any                   # per-experiment fn of a batch slice
-    batch: Dict[str, jnp.ndarray]  # leaves lead with the experiment axis
+def _cohort_batch(cohort: Cohort, varying: Dict[str, jnp.ndarray]
+                  ) -> Dict[str, jnp.ndarray]:
+    """The per-experiment operands of one cohort: its cells' PRNG keys,
+    the scalars that vary, and (ragged) each cell's index into the
+    unique datasets.  Leaves lead with the experiment axis."""
+    batch = {"key": jnp.stack([jax.random.PRNGKey(int(c["seed"]))
+                               for c in cohort.cells]), **varying}
+    if cohort.ragged:
+        pos = {k: i for i, k in enumerate(cohort.data_keys())}
+        batch["didx"] = jnp.asarray(
+            [pos[_data_key(c)] for c in cohort.cells], jnp.int32)
+    return batch
 
 
 @dataclasses.dataclass
@@ -410,13 +408,17 @@ class _CohortContext:
     """
 
     task: Any
-    batch: Dict[str, jnp.ndarray]   # leaves lead with the experiment axis
     data_of: Any
     cfg_of: Any
+    nbytes: int                     # of the data the closures hold
 
 
-def _cohort_context(cohort: Cohort, *, do_eval: bool = True,
+def _cohort_context(cohort: Cohort, uniform: Dict[str, Any],
+                    varying: Sequence[str], *, do_eval: bool = True,
                     eval_data=None) -> _CohortContext:
+    """Build the cohort's task data and close ``data_of`` / ``cfg_of``
+    over it, the ``uniform`` scalar values and the names of the
+    ``varying`` ones (read from the batch)."""
     st = cohort.static
     built = {key: build_task_data(key[0], U=key[1], k_bar=key[2],
                                   data_seed=key[3])
@@ -435,9 +437,6 @@ def _cohort_context(cohort: Cohort, *, do_eval: bool = True,
                 f"k_b={st['k_b']} exceeds the smallest worker's sample "
                 f"count ({min_k}) in this cohort")
 
-    keys = jnp.stack([jax.random.PRNGKey(int(c["seed"]))
-                      for c in cohort.cells])
-    uniform, varying = _split_scalars(cohort.cells)
     u_model = (max(len(built[k][1]) for k in cohort.data_keys()) if ragged
                else len(built[cohort.data_keys()[0]][1]))
 
@@ -446,8 +445,8 @@ def _cohort_context(cohort: Cohort, *, do_eval: bool = True,
         return _cohort_cfg(st, s, u_model)
 
     if ragged:
-        data_batch, uniq, batch_eval = _ragged_batch(cohort, built,
-                                                     do_eval, eval_data)
+        uniq, batch_eval = _ragged_uniques(cohort, built, do_eval,
+                                           eval_data)
         shared_eval = (jnp.asarray(eval_data[0]), jnp.asarray(eval_data[1])
                        ) if (do_eval and eval_data is not None) else None
 
@@ -458,7 +457,7 @@ def _cohort_context(cohort: Cohort, *, do_eval: bool = True,
             return (uniq["X"][d], uniq["Y"][d], uniq["mask"][d],
                     uniq["k_i"][d], uniq["wmask"][d], eval_xy)
 
-        full_batch = {"key": keys, **varying, **data_batch}
+        held = (uniq, shared_eval)
     else:
         # uniform-fleet cohorts keep the data in the closure (not
         # batched), so their per-run graph — and results — are identical
@@ -473,28 +472,218 @@ def _cohort_context(cohort: Cohort, *, do_eval: bool = True,
         def data_of(batch):
             return (X, Y, mask, k_i, None, eval_xy)
 
-        full_batch = {"key": keys, **varying}
+        held = (X, Y, mask, k_i, eval_xy)
+    return _CohortContext(task=task, data_of=data_of, cfg_of=cfg_of,
+                          nbytes=sum(a.nbytes for a in jax.tree.leaves(held)))
 
-    return _CohortContext(task=task, batch=full_batch, data_of=data_of,
-                          cfg_of=cfg_of)
+
+# How many prepared cohort programs a process keeps, and how many bytes
+# of data they may hold; the least recently used is dropped first.  An
+# entry holds its worker data on the device (about 10 MB for the paper's
+# MLP) and one more copy inside each executable it compiled, which
+# embeds the data as constants.  An entry larger than the byte bound is
+# not kept: its cohort runs, and the next one like it builds anew.  A
+# cohort that large spends more on its data and its run than on a trace.
+COHORT_FN_CACHE_SIZE = 8
+COHORT_FN_CACHE_BYTES = 256 << 20
+
+
+class _CohortProgram:
+    """One trace key's per-experiment function, the data it closes over,
+    and its jitted batched callables, one per (mesh, donation)."""
+
+    def __init__(self, ctx: _CohortContext, static: str):
+        self.fns: Dict[Tuple, Any] = {}
+        self.compiled: set = set()     # (mesh, donate, batch shapes) run
+        self.data_bytes = ctx.nbytes
+
+        def run_one(batch):
+            # the body runs only while JAX traces it: one event per trace
+            obs_trace.event("cohort.trace", cat="sweep", static=static)
+            X, Y, mask, k_i, wmask, eval_xy = ctx.data_of(batch)
+            return scan_experiment(ctx.task, X, Y, mask, k_i,
+                                   ctx.cfg_of(batch), batch["key"],
+                                   eval_xy=eval_xy, wmask=wmask)
+
+        self.run_one = run_one
+
+    @property
+    def nbytes(self) -> int:
+        """The data, and the copy each compiled executable embeds."""
+        return self.data_bytes * (1 + len(self.compiled))
+
+
+class _CohortFnCache:
+    """Process-wide LRU of :class:`_CohortProgram` by trace key, bounded
+    in entries and bytes; safe to use from several dispatch threads at
+    once."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._programs: "OrderedDict[Tuple, _CohortProgram]" = OrderedDict()
+
+    def _evict(self) -> None:
+        # under the lock; a dropped program lives on in the cohorts that
+        # hold it until they are done with it
+        while self._programs and (
+                len(self._programs) > COHORT_FN_CACHE_SIZE
+                or sum(p.nbytes for p in self._programs.values())
+                > COHORT_FN_CACHE_BYTES):
+            self._programs.popitem(last=False)
+
+    def program(self, key: Tuple, build) -> _CohortProgram:
+        with self._lock:
+            prog = self._programs.get(key)
+            if prog is not None:
+                self._programs.move_to_end(key)
+                return prog
+        built = build()              # the data build, outside the lock
+        with self._lock:
+            # a thread that missed on the same key at once may have won
+            prog = self._programs.setdefault(key, built)
+            self._programs.move_to_end(key)
+            self._evict()
+        return prog
+
+    def callable(self, prog: _CohortProgram, mesh, donate: bool,
+                 shapes: Tuple) -> Tuple[Any, bool]:
+        with self._lock:
+            fn = prog.fns.get((mesh, donate))
+            if fn is None:
+                fn = prog.fns[(mesh, donate)] = shard_lib.batched_program(
+                    prog.run_one, mesh, donate=donate)
+            hit = (mesh, donate, shapes) in prog.compiled
+            if not hit:
+                prog.compiled.add((mesh, donate, shapes))
+                self._evict()
+        return fn, hit
+
+    def info(self) -> Dict[str, int]:
+        with self._lock:
+            return {"maxsize": COHORT_FN_CACHE_SIZE,
+                    "currsize": len(self._programs),
+                    "maxbytes": COHORT_FN_CACHE_BYTES,
+                    "nbytes": sum(p.nbytes for p in self._programs.values())}
+
+    def clear(self) -> None:
+        with self._lock:
+            self._programs.clear()
+
+
+_COHORT_FNS = _CohortFnCache()
+
+
+def cohort_fn_cache_info() -> Dict[str, int]:
+    """The cache's bounds, the programs it holds and their bytes."""
+    return _COHORT_FNS.info()
+
+
+def cohort_fn_cache_clear() -> None:
+    """Drop every cached cohort program."""
+    _COHORT_FNS.clear()
+
+
+def _static_trace_key(static: Dict[str, Any]) -> Tuple:
+    """The static fields as the trace sees them.  Each value is held
+    with its type (so ``16`` and ``16.0`` differ, and an object's id is
+    never reused while the key lives; a dataclass compares equal only to
+    one of its own class), and a policy or channel given by name with
+    the factory registered under it now."""
+    registries = {"policy": sel_lib._POLICY_REGISTRY,
+                  "channel": chan_lib._CHANNEL_REGISTRY}
+    return tuple(
+        (k, type(v), v, registries[k].get(v)
+         if k in registries and isinstance(v, str) else None)
+        for k, v in static.items())
+
+
+def _content_digest(arrays) -> Optional[str]:
+    if arrays is None:
+        return None
+    h = hashlib.sha256()
+    for a in arrays:
+        a = np.asarray(a)
+        h.update(f"{a.dtype}{a.shape}".encode())
+        h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()
+
+
+@dataclasses.dataclass
+class PreparedCohort:
+    """A cohort's batch and the cached program it runs.
+
+    ``jax.vmap(run_one)`` applied to ``batch`` IS the cohort's whole
+    computation; :func:`dispatch_cohort` runs it through the program's
+    jitted callable, kept per mesh and donation and shared by every
+    cohort of the same trace key.  The split from :func:`run_cohort`
+    exists so the async runtime (``repro.runtime``) can stage host-side
+    preparation, device dispatch, and result finalization on different
+    threads while the serial path composes the same three pieces in
+    order — per-cell results are identical by construction.
+    """
+
+    cohort: Cohort
+    program: _CohortProgram
+    batch: Dict[str, jnp.ndarray]  # leaves lead with the experiment axis
+
+    @property
+    def run_one(self):
+        """The per-experiment function of a batch slice."""
+        return self.program.run_one
 
 
 def prepare_cohort(cohort: Cohort, *, do_eval: bool = True,
                    eval_data=None) -> PreparedCohort:
-    """Host-side phase: build task data, split scalars, close the
-    per-experiment function.  No device computation is dispatched."""
-    ctx = _cohort_context(cohort, do_eval=do_eval, eval_data=eval_data)
-    static = cohort_static_hash(cohort)
+    """Host-side phase: the cohort's batch, and its program from the
+    process's cache, built on a miss (task data, padding, the
+    per-experiment closure).  No device computation is dispatched.
 
-    def run_one(batch):
-        # the body runs only while JAX traces it: one event per trace
-        obs_trace.event("cohort.trace", cat="sweep", static=static)
-        X, Y, mask, k_i, wmask, eval_xy = ctx.data_of(batch)
-        return scan_experiment(ctx.task, X, Y, mask, k_i,
-                               ctx.cfg_of(batch), batch["key"],
-                               eval_xy=eval_xy, wmask=wmask)
+    The cache key holds everything the traced program depends on apart
+    from the batch shapes, which ``jax.jit`` keys itself: the static
+    field values (:func:`_static_trace_key`), the uniform scalar values
+    and the names of the varying ones, the datasets in order (and so
+    whether the cohort is ragged), ``do_eval``, a digest of
+    ``eval_data`` and the 64-bit mode the data were built in.  Cohorts
+    that differ only in their seeds or in the values of varying scalars
+    share one program.
+    """
+    uniform, varying = _split_scalars(cohort.cells)
+    key = (_static_trace_key(cohort.static), tuple(uniform.items()),
+           tuple(varying), tuple(cohort.data_keys()), bool(do_eval),
+           _content_digest(eval_data), bool(jax.config.jax_enable_x64))
 
-    return PreparedCohort(cohort=cohort, run_one=run_one, batch=ctx.batch)
+    def build() -> _CohortProgram:
+        ctx = _cohort_context(cohort, uniform, tuple(varying),
+                              do_eval=do_eval, eval_data=eval_data)
+        return _CohortProgram(ctx, cohort_static_hash(cohort))
+
+    return PreparedCohort(cohort=cohort,
+                          program=_COHORT_FNS.program(key, build),
+                          batch=_cohort_batch(cohort, varying))
+
+
+def dispatch_cohort(prep: PreparedCohort, mesh=None, *,
+                    donate: bool = False, registry=None
+                    ) -> Tuple[Any, Optional[int], bool]:
+    """Dispatch phase: run the cohort's cached jitted callable for
+    ``mesh`` and ``donate`` over its batch, WITHOUT waiting for results
+    (:func:`repro.sweep.shard.dispatch_sharded`).
+
+    Returns ``(out, e, hit)``: ``hit`` says the program already ran on
+    batches of these shapes for this mesh and donation, so JAX's trace
+    cache serves it; on a miss it traces, lowers and compiles (or reads
+    the compile cache).  ``registry`` counts ``cohort_fn_hits`` /
+    ``cohort_fn_misses``.
+    """
+    shapes = tuple((k, v.shape, v.dtype)
+                   for k, v in sorted(prep.batch.items()))
+    fn, hit = _COHORT_FNS.callable(prep.program, mesh, donate, shapes)
+    if registry is not None:
+        registry.counter("cohort_fn_hits" if hit else "cohort_fn_misses",
+                         "cohort dispatches that reused / traced their "
+                         "program").inc()
+    out, e = shard_lib.dispatch_sharded(fn, prep.batch, mesh)
+    return out, e, hit
 
 
 @dataclasses.dataclass
@@ -520,7 +709,9 @@ def prepare_cohort_phases(cohort: Cohort, *, do_eval: bool = True,
     """Host-side phase for blocked execution (same prep as
     :func:`prepare_cohort`; the computation is split at scan
     boundaries)."""
-    ctx = _cohort_context(cohort, do_eval=do_eval, eval_data=eval_data)
+    uniform, varying = _split_scalars(cohort.cells)
+    ctx = _cohort_context(cohort, uniform, tuple(varying), do_eval=do_eval,
+                          eval_data=eval_data)
     static = cohort_static_hash(cohort)
 
     def init_one(batch):
@@ -540,7 +731,8 @@ def prepare_cohort_phases(cohort: Cohort, *, do_eval: bool = True,
                                          eval_xy=eval_xy, wmask=wmask)
         return f
 
-    return CohortPhases(cohort=cohort, batch=ctx.batch, init_one=init_one,
+    return CohortPhases(cohort=cohort, batch=_cohort_batch(cohort, varying),
+                        init_one=init_one,
                         block_one=block_one)
 
 
@@ -550,7 +742,6 @@ def cohort_signature(cohort: Cohort,
     cells (plus the run-level cache extras).  Names checkpoint
     directories, work-stealing claims, and quarantine records — any two
     hosts that would compute the same cells agree on it."""
-    import hashlib
     hs = sorted(store_lib.cell_hash(c, extra) for c in cohort.cells)
     return hashlib.sha256("|".join(hs).encode()).hexdigest()[:16]
 
@@ -560,7 +751,6 @@ def cohort_static_hash(cohort: Cohort) -> str:
     key under which measured walls are persisted (``store.CostBook``).
     Cell-independent: an 8-seed cohort and a 64-seed cohort of the same
     structure share it (costs normalize per cell)."""
-    import hashlib
     import json
     doc = json.dumps(store_lib.jsonable(cohort.static), sort_keys=True)
     return hashlib.sha256(doc.encode()).hexdigest()[:16]
@@ -716,7 +906,7 @@ def finalize_cohort(cohort: Cohort, out: Dict[str, np.ndarray], *,
 
 
 def run_cohort(cohort: Cohort, *, do_eval: bool = True, tail: int = 10,
-               mesh=None, eval_data=None, order: int = 0
+               mesh=None, eval_data=None, order: int = 0, registry=None
                ) -> List[Dict[str, Any]]:
     """Execute one cohort as a single vmapped (and mesh-sharded) program.
 
@@ -727,18 +917,21 @@ def run_cohort(cohort: Cohort, *, do_eval: bool = True, tail: int = 10,
     split (e.g. Fig. 4's fixed held-out set shared across U).
 
     The three phases are the async runtime's, traced under its span
-    names: ``cohort.prepare`` (host data), ``cohort.dispatch`` (trace,
-    lower, compile or cache read, enqueue) and ``cohort.resolve`` (wait
-    for the device, fetch, finalize).  ``order`` is the cohort's
-    position in its plan, which the spans carry as ``cohort``.
+    names: ``cohort.prepare`` (the batch, and on a cache miss the host
+    data), ``cohort.dispatch`` (enqueue of the cached jitted program; on
+    a miss also trace, lower, compile or cache read; ``hit`` says which)
+    and ``cohort.resolve`` (wait for the device, fetch, finalize).
+    ``order`` is the cohort's position in its plan, which the spans
+    carry as ``cohort``; ``registry`` counts the program cache's hits
+    and misses (:func:`dispatch_cohort`).
     """
     with obs_trace.span("cohort.prepare", cat="sweep", cohort=order,
                         cells=len(cohort)):
         prep = prepare_cohort(cohort, do_eval=do_eval, eval_data=eval_data)
     with obs_trace.span("cohort.dispatch", cat="sweep", cohort=order,
-                        cells=len(cohort)):
-        out, e = shard_lib.dispatch_sharded(jax.vmap(prep.run_one),
-                                            prep.batch, mesh)
+                        cells=len(cohort)) as args:
+        out, e, args["hit"] = dispatch_cohort(prep, mesh,
+                                              registry=registry)
     with obs_trace.span("cohort.resolve", cat="sweep", cohort=order,
                         cells=len(cohort)):
         host = shard_lib.resolve(out, e)
@@ -936,7 +1129,7 @@ def run_spec(spec: SweepSpec, *, store: Optional[store_lib.SweepStore] = None,
                     tail=spec.tail, eval_data=eval_data, verbose=verbose)
             return run_cohort(cohort, do_eval=spec.eval, tail=spec.tail,
                               mesh=mesh, eval_data=eval_data,
-                              order=order - 1)
+                              order=order - 1, registry=registry)
 
         # schedule-time prediction (measured walls only): graded against
         # the realized wall below, same contract as the async scheduler

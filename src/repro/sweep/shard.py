@@ -107,38 +107,54 @@ def shard_batch(tree: Any, mesh) -> Any:
     return jax.tree.map(put, tree)
 
 
-def dispatch_sharded(batched_fn, batch: Any, mesh=None, *,
-                     donate: bool = False) -> Tuple[Any, Optional[int]]:
-    """Dispatch ``batched_fn`` over ``batch`` WITHOUT waiting for results.
+def batched_program(run_one, mesh=None, *, donate: bool = False):
+    """The jitted cohort program: ``jax.vmap(run_one)`` over the leading
+    (experiment) axis of a batch, under ``shard_map`` when ``mesh`` is
+    given.
+
+    Build it once per per-experiment function and keep it: JAX's trace
+    cache is keyed on the function object, so every later call with the
+    same batch shapes skips trace and lowering
+    (``repro.sweep.grid`` keeps one per trace key, mesh and donation).
+
+    ``donate=True`` donates the batch buffers to the computation (each
+    cohort builds a fresh batch and never reuses it), bounding the memory
+    held by a dispatch-ahead window; ignored on backends without
+    donation support (CPU) to avoid per-dispatch XLA warnings.
+    """
+    donate_argnums = (0,) if donate and jax.default_backend() != "cpu" \
+        else ()
+    batched = jax.vmap(run_one)
+    if mesh is None:
+        return jax.jit(batched, donate_argnums=donate_argnums)
+    # each device runs the vmapped function on its own slice of the
+    # experiment axis: a Pallas (Mosaic) kernel inside cannot be
+    # partitioned by the compiler, and the experiments never interact
+    exp = P(specs.batch_axes(mesh))
+    return jax.jit(jax.shard_map(batched, mesh=mesh, in_specs=exp,
+                                 out_specs=exp, check_vma=False),
+                   donate_argnums=donate_argnums)
+
+
+def dispatch_sharded(program, batch: Any, mesh=None
+                     ) -> Tuple[Any, Optional[int]]:
+    """Dispatch ``program`` (a :func:`batched_program` built for the same
+    ``mesh``) over ``batch`` WITHOUT waiting for results.
 
     Returns ``(out, e)``: ``out`` holds device arrays (jax's async
     dispatch means the computation may still be running) and ``e`` is the
     original experiment count to ``unpad`` to after fetching (None = no
     padding was applied).  This is the async runtime's dispatch phase —
     the completion writer calls :func:`resolve` on another thread, so
-    device compute overlaps the next cohort's trace/compile and the
-    previous cohort's store I/O.
-
-    ``donate=True`` donates the batch buffers to the computation (they
-    are never reused — each cohort builds a fresh batch), bounding the
-    memory held by a dispatch-ahead window; ignored on backends without
-    donation support (CPU) to avoid per-dispatch XLA warnings.
+    device compute overlaps the next cohort's host work and the previous
+    cohort's store I/O.
     """
-    donate_argnums = (0,) if donate and jax.default_backend() != "cpu" \
-        else ()
     if mesh is None:
-        return jax.jit(batched_fn, donate_argnums=donate_argnums)(batch), None
-    # each device runs the vmapped function on its own slice of the
-    # experiment axis: a Pallas (Mosaic) kernel inside cannot be
-    # partitioned by the compiler, and the experiments never interact
-    exp = P(specs.batch_axes(mesh))
-    fn = jax.jit(jax.shard_map(batched_fn, mesh=mesh, in_specs=exp,
-                               out_specs=exp, check_vma=False),
-                 donate_argnums=donate_argnums)
+        return program(batch), None
     padded, e = pad_batch(batch, shard_count(mesh))
     placed = shard_batch(padded, mesh)
     with jax.set_mesh(mesh):
-        out = fn(placed)
+        out = program(placed)
     return out, e
 
 

@@ -26,9 +26,10 @@ jax.config.update("jax_platform_name", "cpu")
 
 
 @pytest.fixture(autouse=True)
-def _obs_clean():
+def _obs_clean(fresh_cohort_fns):
     """Trace install and log mode are process-global: never leak them
-    into other tests (or between tests here)."""
+    into other tests (or between tests here).  Nor the cohort program
+    cache (``fresh_cohort_fns``)."""
     yield
     trace.uninstall()
     trace.clear_captured()

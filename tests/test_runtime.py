@@ -20,13 +20,14 @@ import numpy as np
 import pytest
 
 from repro.data.tasks import build_task_data
+from repro.obs import metrics, trace
 from repro.runtime import compile_cache
 from repro.runtime import multihost as mh
 from repro.runtime.scheduler import run_cohorts, schedule
 from repro.runtime.writer import Completion, CompletionWriter
 from repro.sweep import SweepSpec, SweepStore, cells, cohort_cost, \
-    cohorts, run_spec
-from repro.sweep.grid import DEFAULTS, _ragged_batch
+    cohorts, grid, run_spec
+from repro.sweep.grid import DEFAULTS, _cohort_batch, _ragged_uniques
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -127,6 +128,47 @@ def test_run_cohorts_sink_called_once_per_cohort():
     assert {id(co) for co, _ in seen} == {id(co) for co in plan}
 
 
+@pytest.mark.parametrize("jobs", [1, 2], ids=["serial", "scheduler"])
+def test_repeated_grid_reuses_cohort_program(tmp_path, jobs,
+                                             fresh_cohort_fns):
+    """A second grid of the same spec on new seeds traces nothing, every
+    dispatch reports a hit, and its results equal those of a run with
+    the cache emptied: the serial path, and the scheduler's donating
+    dispatch threads."""
+    def spec(seeds):
+        return SweepSpec(axes={"policy": ("inflota", "random"),
+                               "seed": seeds},
+                         base={"U": U, "k_bar": K_BAR, "rounds": ROUNDS})
+
+    run_spec(spec((0, 1)), jobs=jobs, dispatch_ahead=0)        # warm-up
+    d = str(tmp_path / "t")
+    trace.install(d)
+    reg = metrics.Registry()
+    warm = run_spec(spec((2, 3)), jobs=jobs, dispatch_ahead=0,
+                    registry=reg)
+    trace.uninstall()
+    events = trace.load_events(d)
+    assert not [e for e in events if e["name"] == "cohort.trace"]
+    dispatches = [e for e in events if e["name"] == "cohort.dispatch"]
+    assert len(dispatches) == 2
+    assert all(e["args"]["hit"] is True for e in dispatches)
+    snap = reg.snapshot()
+    assert snap["cohort_fn_hits"] == 2 and "cohort_fn_misses" not in snap
+
+    grid.cohort_fn_cache_clear()
+    reg = metrics.Registry()
+    cold = run_spec(spec((2, 3)), jobs=jobs, dispatch_ahead=0,
+                    registry=reg)
+    assert reg.snapshot()["cohort_fn_misses"] == 2
+    for w, c in zip(warm, cold):
+        assert w["cell"] == c["cell"]
+        np.testing.assert_array_equal(w["flat"], c["flat"])
+        assert w["history"].keys() == c["history"].keys()
+        for k in w["history"]:
+            np.testing.assert_array_equal(np.asarray(w["history"][k]),
+                                          np.asarray(c["history"][k]))
+
+
 # ------------------------------------------------------------------ writer
 
 def test_writer_resolves_out_of_order():
@@ -208,7 +250,8 @@ def test_ragged_batch_dedups_shared_datasets():
     built = {key: build_task_data(key[0], U=key[1], k_bar=key[2],
                                   data_seed=key[3])
              for key in co.data_keys()}
-    batch, uniques, batch_eval = _ragged_batch(co, built, True, None)
+    uniques, batch_eval = _ragged_uniques(co, built, True, None)
+    batch = _cohort_batch(co, {})
     assert batch["didx"].shape == (8,)
     assert sorted(set(np.asarray(batch["didx"]).tolist())) == [0, 1]
     assert uniques["X"].shape[0] == 2          # unique datasets only

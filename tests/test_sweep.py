@@ -6,22 +6,29 @@ the sweep engine is a pure execution-layout change, never a numerics
 change.
 """
 
+import contextlib
+import dataclasses
 import os
 import subprocess
 import sys
+import threading
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.flatten_util import ravel_pytree
 
+from repro.core import selection as sel
 from repro.core.channel import ChannelConfig, ExpIID, ImperfectCSI
 from repro.core.convergence import LearningConstants
 from repro.core.objectives import Case
 from repro.data.tasks import build_task_data
 from repro.fl.trainer import FLConfig, FLTrainer
+from repro.obs import trace
 from repro.runtime import compile_cache
-from repro.sweep import SweepSpec, SweepStore, cell_hash, run_spec
+from repro.sweep import SweepSpec, SweepStore, cell_hash, grid, run_spec
+from repro.sweep import shard as shard_lib
 from repro.sweep.grid import DEFAULTS, cells, cohorts, result_by
 from repro.sweep.store import canonical_cell, long_rows
 
@@ -204,6 +211,202 @@ def test_result_by_unique_match():
     assert result_by(results, seed=1)["cell"]["seed"] == 1
     with pytest.raises(ValueError, match="2 results"):
         result_by(results, policy="inflota")
+
+
+# ------------------------------------------------------ cohort programs
+
+def _cohort_of(seeds=(0, 1), lr=0.1, data_seed=0, policy="inflota"):
+    axes = {"seed": seeds}
+    if isinstance(lr, tuple):
+        axes["lr"] = lr
+    base = {"U": U, "k_bar": K_BAR, "rounds": 3, "data_seed": data_seed,
+            "backend": "jnp", "policy": policy}
+    if not isinstance(lr, tuple):
+        base["lr"] = lr
+    (co,) = cohorts(cells(SweepSpec(axes=axes, base=base)))
+    return co
+
+
+def _dispatch(co, **kw):
+    """Prepare and run one cohort: (hit, host output)."""
+    prep = grid.prepare_cohort(co, **kw)
+    out, e, hit = grid.dispatch_cohort(prep)
+    return hit, shard_lib.resolve(out, e)
+
+
+def _held_out():
+    return build_task_data("linreg", U=U, k_bar=K_BAR, data_seed=7)[2]
+
+
+@pytest.mark.parametrize("change, traces", [
+    ("seeds", False), ("uniform_lr", True), ("data_seed", True),
+    ("do_eval", True), ("eval_data", True), ("lr_varies", True),
+    ("x64", True)])
+def test_cohort_program_retraces_when_its_trace_inputs_change(
+        tmp_path, change, traces, fresh_cohort_fns):
+    """A cohort that differs from a dispatched one only in its seeds
+    reuses its program; any other trace input traces anew."""
+    co, kw = {
+        "seeds": (_cohort_of(seeds=(5, 6)), {}),
+        "uniform_lr": (_cohort_of(lr=0.05), {}),
+        "data_seed": (_cohort_of(data_seed=1), {}),
+        "do_eval": (_cohort_of(), {"do_eval": False}),
+        "eval_data": (_cohort_of(), {"eval_data": _held_out()}),
+        "lr_varies": (_cohort_of(lr=(0.1, 0.05)), {}),
+        "x64": (_cohort_of(), {}),
+    }[change]
+    mode = (jax.enable_x64(not jax.config.jax_enable_x64)
+            if change == "x64" else contextlib.nullcontext())
+    _dispatch(_cohort_of())
+    d = str(tmp_path / "t")
+    trace.install(d)
+    with mode:                         # the data are built in this mode
+        hit, _ = _dispatch(co, **kw)
+    trace.uninstall()
+    n = len([e for e in trace.load_events(d) if e["name"] == "cohort.trace"])
+    assert (hit, n) == ((False, 1) if traces else (True, 0))
+
+
+def test_cohort_program_keys_eval_data_by_content(fresh_cohort_fns):
+    ex, ey = _held_out()
+    _dispatch(_cohort_of(), eval_data=(ex, ey))
+    assert _dispatch(_cohort_of(), eval_data=(ex.copy(), ey.copy()))[0]
+    assert not _dispatch(_cohort_of(), eval_data=(ex, ey + 1.0))[0]
+
+
+def _policy_class(top_half):
+    """A policy class of the same name and fields whatever its body: every
+    worker transmits, or those with the better half of the channels."""
+    @dataclasses.dataclass(frozen=True)
+    class SketchPolicy(sel.RoundPolicyBase):
+        def decide(self, key, ctx):
+            D = ctx.w_prev_abs.shape[0]
+            beta = ((ctx.h_est >= jnp.median(ctx.h_est)) if top_half
+                    else jnp.ones_like(ctx.h_est)).astype(jnp.float32)
+            return sel.make_decision(jnp.ones((D,)), beta[:, None],
+                                     ctx.k_eff, ctx.k_i, wmask=ctx.wmask)
+    return SketchPolicy
+
+
+@pytest.mark.parametrize("given", ["instance", "name"])
+def test_cohort_program_tells_redefined_policies_apart(
+        tmp_path, monkeypatch, given, fresh_cohort_fns):
+    """Two policies of one class name and equal fields but different
+    ``decide`` bodies (a class defined anew, or a name registered anew)
+    each trace, and each cohort gives what it gives on an empty cache."""
+    def policy(top_half):
+        cls = _policy_class(top_half)
+        if given == "instance":
+            return cls()
+        monkeypatch.setitem(sel._POLICY_REGISTRY, "sketch",
+                            lambda **kw: cls())
+        return "sketch"
+
+    outs = []
+    for top_half in (False, True):
+        co = _cohort_of(policy=policy(top_half))
+        d = str(tmp_path / f"t{top_half}")
+        trace.install(d)
+        hit, out = _dispatch(co)
+        trace.uninstall()
+        assert not hit
+        assert [e["name"] for e in trace.load_events(d)
+                if e["name"] == "cohort.trace"] == ["cohort.trace"]
+        outs.append(out)
+    grid.cohort_fn_cache_clear()
+    alone = _dispatch(co)[1]
+    for k in alone:
+        np.testing.assert_array_equal(np.asarray(outs[1][k]),
+                                      np.asarray(alone[k]))
+    assert not np.array_equal(np.asarray(outs[0]["selected"]),
+                              np.asarray(outs[1]["selected"]))
+
+
+@pytest.mark.parametrize("bound", ["entries", "bytes"])
+def test_cohort_program_cache_evicts_least_recent(monkeypatch, bound,
+                                                  fresh_cohort_fns):
+    """Past its bound in programs, or in bytes of the data they hold,
+    the cache drops the least recently used program."""
+    def program(lr):
+        return grid.prepare_cohort(_cohort_of(lr=lr)).program
+
+    a = program(0.1)
+    if bound == "entries":
+        monkeypatch.setattr(grid, "COHORT_FN_CACHE_SIZE", 2)
+    else:                                      # room for two programs
+        monkeypatch.setattr(grid, "COHORT_FN_CACHE_BYTES", 2 * a.nbytes)
+    b = program(0.2)
+    assert program(0.1) is a                   # a is now the most recent
+    program(0.3)                               # evicts b
+    assert grid.cohort_fn_cache_info()["currsize"] == 2
+    assert program(0.1) is a
+    assert program(0.2) is not b
+
+
+def test_cohort_program_bytes_count_each_compiled_copy(monkeypatch,
+                                                       fresh_cohort_fns):
+    """A program holds its data once, and once more for each batch shape
+    it compiled; one larger than the byte bound runs but is not kept."""
+    def nbytes():
+        return grid.cohort_fn_cache_info()["nbytes"]
+
+    prep = grid.prepare_cohort(_cohort_of())
+    data = prep.program.nbytes
+    assert data > 0 and nbytes() == data
+    assert not grid.dispatch_cohort(prep)[2]
+    assert nbytes() == 2 * data
+    assert _dispatch(_cohort_of(seeds=(4, 5)))[0]    # same shapes
+    assert nbytes() == 2 * data
+    assert not _dispatch(_cohort_of(seeds=(0, 1, 2)))[0]
+    assert nbytes() == 3 * data
+
+    grid.cohort_fn_cache_clear()
+    monkeypatch.setattr(grid, "COHORT_FN_CACHE_BYTES", data - 1)
+    hit, out = _dispatch(_cohort_of())
+    assert not hit and out["selected"].shape[0] == 2
+    assert grid.cohort_fn_cache_info()["currsize"] == 0
+    assert not _dispatch(_cohort_of())[0]      # built anew
+
+
+def test_threads_dispatching_one_program_get_their_own_results(
+        fresh_cohort_fns):
+    """Two threads prepare and dispatch cohorts of one trace key at once;
+    each gets what a lone run of its cohort gives."""
+    seed_sets = [(0, 1), (2, 3), (4, 5), (6, 7)]
+    alone = {}
+    for seeds in seed_sets:
+        grid.cohort_fn_cache_clear()
+        alone[seeds] = _dispatch(_cohort_of(seeds=seeds))[1]
+    grid.cohort_fn_cache_clear()
+    got, errors = {}, []
+    barrier = threading.Barrier(2)
+
+    def work(mine):
+        try:
+            barrier.wait(timeout=30)
+            for seeds in mine:
+                got[seeds] = _dispatch(_cohort_of(seeds=seeds))[1]
+        except Exception as e:                 # noqa: BLE001 — reported
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(seed_sets[i::2],))
+                   for i in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors
+    assert grid.cohort_fn_cache_info()["currsize"] == 1
+    for seeds in seed_sets:
+        for k in alone[seeds]:
+            np.testing.assert_array_equal(np.asarray(got[seeds][k]),
+                                          np.asarray(alone[seeds][k]))
 
 
 # ---------------------------------------------------------------- sharding
