@@ -16,7 +16,7 @@ numbers are not blended by async dispatch):
   * ``round_wall_s``  steady-state end-to-end round time;
   * ``search_s``      the distributed Theorem-4 sorted-prefix search;
   * ``tx_kernel_s``   the S streamed ``ota_shard_tx`` tile kernels;
-  * ``combine_s``     the cross-shard (S, D) partial reduction — the
+  * ``combine_s``     the descale with its blockwise beta reduction — the
                       part that becomes a psum/all_gather on a mesh,
                       reported separately from kernel time on purpose.
 
@@ -85,8 +85,8 @@ def _bench_u(U: int, rounds: int, u_b: int, reps: int) -> Dict[str, float]:
     snr = float(stats.snr)
 
     # phase thunks over the same shapes the round streams: the search on
-    # this round's CSI, one scan of S transmit tile kernels, and the
-    # fixed-order (S, D) partial combine
+    # this round's CSI, one scan of S transmit tile kernels onto the
+    # carried y, and the descale with its blockwise beta reduction
     c = cfg.constants
     key = jax.random.PRNGKey(1)
     h = jax.random.exponential(key, (U,))
@@ -104,27 +104,28 @@ def _bench_u(U: int, rounds: int, u_b: int, reps: int) -> Dict[str, float]:
 
     @jax.jit
     def tx_stream(blk, b, s):
-        def body(_, xs):
-            return None, kops.ota_shard_tx(
+        def body(y, xs):
+            return kops.ota_shard_tx(
                 Wb, xs["h"], xs["h"], xs["cw"], s, b, xs["k"], xs["k"],
-                xs["p"])
-        _, parts = jax.lax.scan(body, None, blk)
-        return parts
+                xs["p"], y=y), None
+        y, _ = jax.lax.scan(body, jnp.zeros_like(b), blk)
+        return y
 
-    parts = jax.block_until_ready(tx_stream(blocked, sol.b, sol.s))
+    y = jax.block_until_ready(tx_stream(blocked, sol.b, sol.s))
 
     @jax.jit
-    def combine(ps, b):
-        ys, denks, denis, sels = ps
-        y = jnp.sum(ys, axis=0)
-        return (y / jnp.maximum(jnp.sum(denks, axis=0) * b, 1e-12),
-                jnp.sum(denis, axis=0), jnp.sum(sels, axis=0))
+    def combine(y, b):
+        den = jax.lax.fori_loop(
+            0, S, lambda j, acc: acc + jnp.sum(
+                blocked["k"][j][:, None] * inflota.block_beta(
+                    b, sol.cw[j], sol.s), axis=0), jnp.zeros_like(b))
+        return y / jnp.maximum(den * b, 1e-12)
 
     times = common.phase_times({
         "round_wall_s": lambda: step(st)[0].flat,
         "search_s": lambda: search(h).b,
-        "tx_kernel_s": lambda: tx_stream(blocked, sol.b, sol.s)[0],
-        "combine_s": lambda: combine(parts, sol.b)[0],
+        "tx_kernel_s": lambda: tx_stream(blocked, sol.b, sol.s),
+        "combine_s": lambda: combine(y, sol.b),
     }, reps=reps)
     return {"snr_final_db": 10.0 * float(np.log10(max(snr, 1e-30))),
             "shards": float(S), **times}

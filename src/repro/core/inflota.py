@@ -271,13 +271,16 @@ def block_envelope(cw_blk, den_blk, s, c: LearningConstants, numer):
              + (numer / (2.0 * c.L
                          * jnp.maximum(den_blk, _EPS)))[:, None])
     kloc = jnp.argmin(r_blk, axis=0)
-    rmin = jnp.take_along_axis(r_blk, kloc[None, :], axis=0)[0]
+    # the min is the value at the argmin, bit for bit, with no (D,)-long
+    # gather index array
+    rmin = jnp.min(r_blk, axis=0)
     cw_star = jnp.take(cw_blk, kloc)
     return rmin, kloc, cw_star
 
 
 def reduce_envelopes(rmin, kloc, cw_star, s, u_b: int):
-    """Cross-shard argmin of the stacked per-shard envelopes.
+    """Cross-shard argmin of the stacked per-shard envelopes (the
+    comparison form; the engine folds shards with ``envelope_step``).
 
     ``jnp.argmin`` over the shard axis keeps the FIRST shard attaining
     the minimum, and each shard's ``kloc`` is its first local minimizer,
@@ -290,6 +293,25 @@ def reduce_envelopes(rmin, kloc, cw_star, s, u_b: int):
              + sidx.astype(kloc.dtype) * u_b)
     b = jnp.take_along_axis(cw_star, sidx[None, :], axis=0)[0] * s
     return b, r, kstar.astype(jnp.int32)
+
+
+def envelope_step(carry, cw_blk, den_blk, s, c: LearningConstants, numer,
+                  offset):
+    """Fold one shard's envelope into the running per-entry first-min.
+
+    ``carry`` is (rmin (D,), kstar (D,) i32) after the earlier shards,
+    starting from (+inf, 0); ``offset`` is this shard's first global
+    worker index.  A later shard wins an entry only when strictly
+    better, so ties keep the earlier shard, and each shard's own argmin
+    keeps its first local minimizer: the result is bit for bit what
+    ``reduce_envelopes`` takes from the stacked envelopes, with no
+    (S, D) stack.  The winner's b is ``cw[kstar] * s``.
+    """
+    rmin, kstar = carry
+    r_blk, kloc, _ = block_envelope(cw_blk, den_blk, s, c, numer)
+    take = r_blk < rmin
+    return (jnp.where(take, r_blk, rmin),
+            jnp.where(take, kloc.astype(jnp.int32) + offset, kstar))
 
 
 def block_beta(b, cw_blk, s, dt=jnp.float32):

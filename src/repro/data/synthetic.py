@@ -8,6 +8,9 @@
   images + pixel noise, linearly separable only partially (test accuracy
   saturates < 100%, like MNIST).
 * token_stream: deterministic synthetic token batches for LM training.
+* markov_tokens: sequences from a fixed sparse Markov source over a
+  vocabulary, the FL language-model task's data (a next-token CE that
+  can fall, which uniform random ids would not give).
 """
 
 from __future__ import annotations
@@ -57,3 +60,27 @@ def token_stream(batch: int, seq: int, vocab: int,
         toks[:, 1::2] = nxt[:, 0::2][:, :toks[:, 1::2].shape[1]]
         yield {"tokens": toks.astype(np.int32),
                "labels": toks.astype(np.int32)}
+
+
+def markov_tokens(n_seq: int, seq_len: int, vocab: int, seed: int = 0,
+                  fanout: int = 4, source_seed: int = 0) -> np.ndarray:
+    """(n_seq, seq_len) int32 ids from a fixed sparse Markov source.
+
+    The source (``source_seed``) gives every id ``fanout`` successors
+    with Dirichlet(1) probabilities; ``seed`` draws each sequence's first
+    id uniformly and its later ids from the source, all sequences
+    stepped together.
+    """
+    src = np.random.default_rng(source_seed)
+    succ = src.integers(0, vocab, size=(vocab, fanout))
+    cum = np.cumsum(src.dirichlet(np.ones(fanout), size=vocab), axis=1)
+    rng = np.random.default_rng(seed)
+    tok = np.empty((n_seq, seq_len), np.int64)
+    tok[:, 0] = rng.integers(0, vocab, size=n_seq)
+    u = rng.random((n_seq, seq_len - 1))
+    for t in range(1, seq_len):
+        prev = tok[:, t - 1]
+        j = np.minimum(np.sum(u[:, t - 1, None] > cum[prev], axis=1),
+                       fanout - 1)
+        tok[:, t] = succ[prev, j]
+    return tok.astype(np.int32)

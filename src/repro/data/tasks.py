@@ -35,7 +35,8 @@ _TASK_REGISTRY: Dict[str, Callable[..., TaskData]] = {}
 # scheduler uses to order cohort dispatch (cells x rounds x U_max x D)
 # WITHOUT building any task data.  Unknown tasks fall back to 1: ordering
 # degrades gracefully, correctness never depends on it.
-_DIM_HINTS: Dict[str, int] = {"linreg": 3, "ridge": 8, "mlp": 50890}
+_DIM_HINTS: Dict[str, int] = {"linreg": 3, "ridge": 8, "mlp": 50890,
+                               "moonlight_lm": 568_484_352}
 
 
 def dim_hint(name: Any, default: int = 1) -> int:
@@ -104,3 +105,32 @@ def _mlp(U: int = 20, k_bar: int = 40, data_seed: int = 0,
     workers = partition.partition(x[:-n_test], y[:-n_test], counts,
                                   seed=data_seed)
     return mlp_model(), workers, (x[-n_test:], y[-n_test:])
+
+
+@register_task("moonlight_lm")
+def _moonlight_lm(U: int = 20, k_bar: int = 40, data_seed: int = 0,
+                  n_test: int = 8, seq_len: int = 1024,
+                  **shape: Any) -> TaskData:
+    """Moonlight-16B-A3B's one-chip share (``fl/lm_models.py``; D =
+    568,484,352) as the local model: worker i holds K_i ~ round(U[K̄-5,
+    K̄+5]) sequences of ``seq_len + 1`` ids from a fixed sparse Markov
+    source over the vocabulary slice, as (inputs, next ids) pairs.
+    ``shape`` overrides ``LMShape`` widths (small models in tests).
+    Runs only in the worker-sharded engine (``FLConfig.worker_sharding``).
+    """
+    from repro.fl import lm_models
+    from repro.obs import trace
+
+    lm_shape = lm_models.LMShape(**shape)
+    D = lm_models.param_count(lm_shape)
+    with trace.span("task.build", cat="task", task="moonlight_lm", D=D,
+                    bytes=4 * D):
+        counts = partition.sample_counts(U, k_bar, seed=data_seed)
+        tok = synthetic.markov_tokens(int(np.sum(counts)) + n_test,
+                                      seq_len + 1, lm_shape.vocab,
+                                      seed=data_seed)
+        x, y = tok[:, :-1], tok[:, 1:]
+        ends = np.cumsum(counts)
+        workers = [(x[e - k:e], y[e - k:e]) for k, e in zip(counts, ends)]
+        return (lm_models.moonlight_model(lm_shape), workers,
+                (x[-n_test:], y[-n_test:]))

@@ -58,6 +58,27 @@ def local_update(task, params, x, y, lr: float, *, key=None,
     return p
 
 
+def local_grad_masked(task, params, x, y, mask, *, key,
+                      k_b: int | None = None):
+    """The gradient of one masked local step over a K_max-padded sample
+    block (one worker): of the mask-weighted mean loss over the real
+    samples, or over ``k_b`` of them drawn with ``key``.  See
+    ``local_update_masked``, which steps with it."""
+    def masked_loss(p, xb, yb, mb):
+        per = jax.vmap(lambda xi, yi: task.loss(p, xi[None], yi[None]))(
+            xb, yb)
+        return jnp.sum(per * mb) / jnp.maximum(jnp.sum(mb), 1.0)
+
+    if k_b is not None:
+        # restriction-stable draw over the worker's real samples only
+        idx = minibatch_indices(key, mask, k_b)
+        xb, yb = x[idx], y[idx]
+        mb = jnp.ones((k_b,), mask.dtype)
+    else:
+        xb, yb, mb = x, y, mask
+    return jax.grad(masked_loss)(params, xb, yb, mb)
+
+
 def local_update_masked(task, params, x, y, mask, lr: float, *, key,
                         k_b: int | None = None, steps: int = 1):
     """Masked local update over a K_max-padded sample block (one worker).
@@ -71,26 +92,12 @@ def local_update_masked(task, params, x, y, mask, lr: float, *, key,
 
     ``task.loss`` is only assumed to be a mean of per-sample losses (true
     for every TaskModel here); it is re-weighted by evaluating it per
-    sample under an inner vmap.
+    sample under an inner vmap.  Step ``s`` draws with
+    ``split(key, steps)[s]``.
     """
-    def masked_loss(p, xb, yb, mb):
-        per = jax.vmap(lambda xi, yi: task.loss(p, xi[None], yi[None]))(
-            xb, yb)
-        return jnp.sum(per * mb) / jnp.maximum(jnp.sum(mb), 1.0)
-
-    def one_step(p, k):
-        if k_b is not None:
-            # restriction-stable draw over the worker's real samples only
-            idx = minibatch_indices(k, mask, k_b)
-            xb, yb = x[idx], y[idx]
-            mb = jnp.ones((k_b,), mask.dtype)
-        else:
-            xb, yb, mb = x, y, mask
-        g = jax.grad(masked_loss)(p, xb, yb, mb)
-        return jax.tree.map(lambda w, gg: w - lr * gg, p, g)
-
     p = params
     keys = jax.random.split(key, steps)
     for s in range(steps):
-        p = one_step(p, keys[s])
+        g = local_grad_masked(task, p, x, y, mask, key=keys[s], k_b=k_b)
+        p = jax.tree.map(lambda w, gg: w - lr * gg, p, g)
     return p

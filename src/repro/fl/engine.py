@@ -331,6 +331,12 @@ def build_engine(task, X, Y, mask, k_i, cfg: FLConfig, params0,
         from repro.fl import worker_shard
         return worker_shard.build_sharded_engine(
             task, X, Y, mask, k_i, cfg, params0, wmask=wmask)
+    if getattr(task, "sharded_only", False):
+        raise ValueError(
+            "this task model runs only in the worker-sharded engine: the "
+            "dense engine holds every worker's (D,) local update at once "
+            "(U x D floats); set FLConfig.worker_sharding (U_shards in a "
+            "sweep), e.g. to U for one worker a block")
     flat0, unravel = ravel_pytree(params0)
     D = flat0.shape[0]
     U = k_i.shape[0]

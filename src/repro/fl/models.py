@@ -26,6 +26,9 @@ class TaskModel:
     init: Callable[[Any], Any]
     loss: Callable[[Any, Any, Any], Any]
     metrics: Callable[[Any, Any, Any], Dict[str, Any]]
+    # a model whose (U, D) block of local updates no device holds runs
+    # only in the worker-sharded engine (``FLConfig.worker_sharding``)
+    sharded_only: bool = False
 
 
 def matmul(a, b):

@@ -38,15 +38,19 @@ def ota_round(w, h, w_abs, eta, noise, k_eff, k_i, p_max, numer,
 
 
 def ota_shard_tx(w, h, h_est, cw, s, b, k_eff, k_i, p_max, wmask=None,
-                 block_d: int = 1024, interpret: bool | None = None):
-    """One worker-shard block's fused transmit partials (see
-    kernels.ota_round.ota_shard_tx): the (U_b, D) beta tile is rebuilt
-    in VMEM from the rank-1 ``(cw, s)`` factorization and only (D,)
-    partial reductions leave the kernel."""
+                 *, y, block_d: int = 16384, interpret: bool | None = None):
+    """One worker-shard block's fused transmit added onto the carried
+    superposition ``y`` (see kernels.ota_round.ota_shard_tx): the
+    (U_b, D) beta tile is rebuilt in VMEM from the rank-1 ``(cw, s)``
+    factorization and only the updated (D,) ``y`` leaves the kernel.
+    ``k_i`` (the true sample counts) is taken with the block's other
+    per-worker columns and not read: the beta reductions that weigh by
+    it are the engine's."""
+    del k_i
     if interpret is None:
         interpret = _default_interpret()
     return _round.ota_shard_tx(
-        w, h, h_est, cw, s, b, k_eff, k_i, p_max, wmask,
+        w, h, h_est, cw, s, b, k_eff, p_max, wmask, y=y,
         block_d=block_d, interpret=interpret)
 
 

@@ -113,8 +113,7 @@ def _kernel(w_ref, h_ref, hest_ref, wabs_ref, eta_ref, z_ref,
 
 
 def _shard_tx_kernel(w_ref, h_ref, hest_ref, cw_ref, s_ref, b_ref,
-                     keff_ref, ki_ref, pmax_ref, wm_ref,
-                     y_ref, denk_ref, deni_ref, sel_ref):
+                     keff_ref, pmax_ref, wm_ref, yin_ref, y_ref):
     w = w_ref[...]            # (U_b, blk) this shard block's local updates
     h = h_ref[...]            # (U_b, 1)   true gains (rank-1)
     h_est = hest_ref[...]     # (U_b, 1)   CSI estimate
@@ -122,7 +121,6 @@ def _shard_tx_kernel(w_ref, h_ref, hest_ref, cw_ref, s_ref, b_ref,
     s = s_ref[...]            # (1, blk)   1 / (|w_{t-1}| + eta)
     b = b_ref[...]            # (1, blk)   the DECIDED global power scaling
     k_eff = keff_ref[...]     # (U_b, 1)
-    k_i = ki_ref[...]         # (U_b, 1)
     p_max = pmax_ref[...]     # (U_b, 1)
     wm = wm_ref[...]          # (U_b, 1)   real-worker mask (ones if none)
 
@@ -134,24 +132,19 @@ def _shard_tx_kernel(w_ref, h_ref, hest_ref, cw_ref, s_ref, b_ref,
     # amp as there): workers invert the ESTIMATE, the MAC applies true h
     amp = jnp.abs(beta * k_eff * b / h_est * w)
     tx = beta * jnp.sign(w) * jnp.minimum(amp, jnp.sqrt(p_max))
-    parts = (jnp.sum(tx * h, axis=0, keepdims=True),           # (1, blk)
-             jnp.sum(k_eff * beta, axis=0, keepdims=True),
-             jnp.sum(k_i * beta, axis=0, keepdims=True),
-             jnp.sum(beta, axis=0, keepdims=True))
-    outs = (y_ref, denk_ref, deni_ref, sel_ref)
-    # the worker axis is the inner grid axis: the (1, blk) outputs stay
-    # resident across it and accumulate the worker steps in order
+    part = jnp.sum(tx * h, axis=0, keepdims=True)              # (1, blk)
+    # the worker axis is the inner grid axis: the (1, blk) output stays
+    # resident across it and adds the worker steps in order onto the
+    # carried superposition (aliased in and out, so no new (D,) buffer)
     first = pl.program_id(1) == 0
 
     @pl.when(first)
     def _():
-        for ref, p in zip(outs, parts):
-            ref[...] = p
+        y_ref[...] = yin_ref[...] + part
 
     @pl.when(jnp.logical_not(first))
     def _():
-        for ref, p in zip(outs, parts):
-            ref[...] += p
+        y_ref[...] += part
 
 
 def scalar_row(L, sigma2, numer, dt):
@@ -160,37 +153,53 @@ def scalar_row(L, sigma2, numer, dt):
                       for v in (L, sigma2, numer)])[None, :]
 
 
-# VMEM for the double-buffered inputs of one ``ota_shard_tx`` grid step:
-# the (block_u, block_d) ``w`` tile and its seven (block_u, 1) worker
-# columns, each lane-padded to 128.  Half of v5e's 16 MiB default scoped
-# VMEM, leaving the rest to the kernel's (block_u, block_d) temporaries.
-_SHARD_TX_VMEM = 8 << 20
+# VMEM one ``ota_shard_tx`` grid step may take, of v5e's 16 MiB default
+# scoped VMEM: the double-buffered (block_u, block_d) ``w`` tile and four
+# kernel temporaries of its size, six (block_u, 1) worker columns
+# lane-padded to 128 and double-buffered, and four (1, block_d) rows
+# (s, b, y in and out) sublane-padded to 8 and double-buffered.
+_SHARD_TX_VMEM = 12 << 20
+
+
+def _shard_tx_step_bytes(rows: int, lanes: int) -> int:
+    lp = -(-lanes // 128) * 128
+    rp = -(-rows // 8) * 8
+    return 4 * (6 * rp * lp + 2 * 6 * 128 * rp + 2 * 4 * 8 * lp)
 
 
 def _shard_tx_tiles(U_b: int, D: int, block_d: int):
-    """(block_u, block_d) of ``ota_shard_tx``: lanes no wider than D needs,
-    and as few worker steps as fit ``_SHARD_TX_VMEM``.  One step
-    (block_u = U_b) whenever the whole block fits."""
-    block_d = min(block_d, -(-D // 128) * 128)
-    max_rows = max(8, _SHARD_TX_VMEM // (4 * 2 * (block_d + 7 * 128))
-                   // 8 * 8)
+    """(block_u, block_d) of ``ota_shard_tx``.  Lanes: D itself when it is
+    no wider than ``block_d`` (a full-width block needs no padding),
+    else ``block_d``, whose last tile the grid leaves partial; narrowed
+    (down to 1,024) while the whole worker block does not fit
+    ``_SHARD_TX_VMEM``.  Rows: one step (block_u = U_b) whenever the
+    whole block fits, else as few worker steps as fit."""
+    lanes = D if D <= block_d else block_d
+    while lanes > 1024 and _shard_tx_step_bytes(U_b, lanes) > _SHARD_TX_VMEM:
+        lanes = max(1024, lanes // 2 // 128 * 128)
+    if _shard_tx_step_bytes(U_b, lanes) <= _SHARD_TX_VMEM:
+        return U_b, lanes
+    lp = -(-lanes // 128) * 128
+    max_rows = max(8, (_SHARD_TX_VMEM // 4 - 64 * lp)
+                   // (6 * lp + 1536) // 8 * 8)
     steps = -(-U_b // max_rows)
-    if steps == 1:
-        return U_b, block_d
     rows = -(-U_b // steps)
-    return -(-rows // 8) * 8, block_d
+    return -(-rows // 8) * 8, lanes
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
-def ota_shard_tx(w, h, h_est, cw, s, b, k_eff, k_i, p_max, wmask=None,
-                 *, block_d: int = 1024, interpret: bool):
-    """One worker-shard block's transmit partials, fused in VMEM.
+def ota_shard_tx(w, h, h_est, cw, s, b, k_eff, p_max, wmask=None,
+                 *, y, block_d: int = 16384, interpret: bool):
+    """One worker-shard block's transmit, added onto the superposition.
 
     The worker-sharded engine (``fl/worker_shard.py``) decides ``b``
     globally with the sharded Theorem-4 solver, then streams shard
     blocks through this kernel: the (U_b, D) beta tile is rebuilt from
     the rank-1 factorization ``(cw, s)`` inside VMEM (never written to
-    HBM) and only the four (D,) partial reductions leave the kernel.
+    HBM) and the block's received signal is added onto ``y``, the
+    superposition of the blocks before it, in place.  The per-entry
+    beta reductions are the engine's (they depend on b, s and the
+    per-worker scalars only).
 
     Args:
       w:      (U_b, D) the block's local parameter vectors.
@@ -199,63 +208,56 @@ def ota_shard_tx(w, h, h_est, cw, s, b, k_eff, k_i, p_max, wmask=None,
       cw:     (U_b,) candidate coefficients |sqrt(P) h_est / k|.
       s:      (D,)   the 1 / (|w_{t-1}| + eta) statistic.
       b:      (D,)   decided per-entry power scaling (global optimum).
-      k_eff:  (U_b,) descale weights; k_i: (U_b,) true sample counts;
-      p_max:  (U_b,) power budgets; wmask: optional (U_b,) real-worker
-              mask (None = all real; multiplying by 1.0 is exact).
-      block_d: widest lane tile; narrowed to what D needs.
+      k_eff:  (U_b,) descale weights; p_max: (U_b,) power budgets;
+              wmask: optional (U_b,) real-worker mask (None = all real;
+              multiplying by 1.0 is exact).
+      y:      (D,) the superposition so far (aliased to the output).
+      block_d: widest lane tile (see ``_shard_tx_tiles``).
       interpret: run the Pallas interpreter (CPU) instead of compiling
               for the TPU; ``kernels.ops`` picks it from the backend.
 
     The grid walks (D tiles, worker tiles).  A block too large for VMEM
     is split into worker tiles padded with inert workers (zero mask and
-    budget, unit gains), whose partials are exactly zero; the tiles'
-    partials are summed in order.  A block that fits takes one worker
-    step, which reduces in the same order as the jnp block ops.
+    budget, unit gains), whose terms are exactly zero; the tiles are
+    added in order.  A block that fits takes one worker step, which
+    reduces in the same order as the jnp block ops.
 
-    Returns (y_p, denk_p, deni_p, sel_p), each (D,): the block's
-    superposition partial (no noise) and the three beta reductions
-    (denk_p WITHOUT the * b — the combiner applies it after the
-    cross-shard sum, mirroring ``selection.make_decision``).
+    Returns y + the block's superposition (no noise), (D,).
     """
     U_b, D = w.shape
     dt = jnp.result_type(w.dtype, jnp.float32)
     if wmask is None:
         wmask = jnp.ones((U_b,), dt)
     block_u, block_d = _shard_tx_tiles(U_b, D, block_d)
-    pad = (-D) % block_d
-    if pad:
-        w = jnp.pad(w, ((0, 0), (0, pad)))
-        s = jnp.pad(s, (0, pad), constant_values=1.0)
-        b = jnp.pad(b, (0, pad))
-    Dp = D + pad
-    cols = [jnp.asarray(v, dt)
-            for v in (h, h_est, cw, k_eff, k_i, p_max, wmask)]
+    cols = [jnp.asarray(v, dt) for v in (h, h_est, cw, k_eff, p_max, wmask)]
     pad_u = (-U_b) % block_u
     if pad_u:
         w = jnp.pad(w, ((0, pad_u), (0, 0)))
         # h, h_est pad with 1 (the inversion divides by h_est); the
-        # rest with 0, so the mask and budget zero every partial
+        # rest with 0, so the mask and budget zero every term
         cols = [jnp.pad(v, (0, pad_u), constant_values=float(i < 2))
                 for i, v in enumerate(cols)]
     row = pl.BlockSpec((1, block_d), lambda i, j: (0, i))
     col = pl.BlockSpec((block_u, 1), lambda i, j: (j, 0))
-    y, denk, deni, sel = pl.pallas_call(
+    out = pl.pallas_call(
         _shard_tx_kernel,
-        grid=(Dp // block_d, (U_b + pad_u) // block_u),
+        grid=(pl.cdiv(D, block_d), (U_b + pad_u) // block_u),
         in_specs=[
             pl.BlockSpec((block_u, block_d), lambda i, j: (j, i)),   # w
             col, col, col,                                    # h, h_est, cw
             row, row,                                         # s, b
-            col, col, col, col,                    # k_eff, k_i, p_max, wm
+            col, col, col,                           # k_eff, p_max, wm
+            row,                                              # y in
         ],
-        out_specs=[row, row, row, row],
-        out_shape=[jax.ShapeDtypeStruct((1, Dp), dt)] * 4,
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((1, D), dt),
+        input_output_aliases={9: 0},
         interpret=interpret,
         name="ota_shard_tx",        # the kernel's name in a profiler trace
     )(w.astype(dt), *[v[:, None] for v in cols[:3]],
       jnp.asarray(s, dt)[None, :], jnp.asarray(b, dt)[None, :],
-      *[v[:, None] for v in cols[3:]])
-    return (y[0, :D], denk[0, :D], deni[0, :D], sel[0, :D])
+      *[v[:, None] for v in cols[3:]], jnp.asarray(y, dt)[None, :])
+    return out[0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))

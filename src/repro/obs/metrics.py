@@ -278,3 +278,13 @@ class Registry:
                        "metrics": self.snapshot()}, f, indent=2,
                       sort_keys=True)
             f.write("\n")
+
+
+_PROCESS = Registry(namespace="repro")
+
+
+def process_registry() -> Registry:
+    """The process's own registry (namespace ``repro``): series that
+    library code sets where no caller hands it a registry, such as the
+    worker-sharded engine's build-time gauges."""
+    return _PROCESS

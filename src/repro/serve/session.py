@@ -294,10 +294,10 @@ class SweepService:
         return (hits / served) if served else 0.0
 
     def metrics_text(self) -> str:
-        """Prometheus exposition of the daemon registry (the /metrics
-        endpoint).  Label-carrying series (per-client admission charge,
-        store health notes) are refreshed here — everything else is a
-        live counter or a callback gauge."""
+        """Prometheus exposition of the daemon registry and the process
+        registry (the /metrics endpoint).  Label-carrying series
+        (per-client admission charge, store health notes) are refreshed
+        here — everything else is a live counter or a callback gauge."""
         g = self.registry.gauge("admission_queued_s",
                                 "queued device-seconds per client")
         g.clear_labeled()
@@ -308,7 +308,10 @@ class SweepService:
         notes.clear_labeled()
         for kind, n in self.store.health()["note_counts"].items():
             notes.set_labeled(n, kind=str(kind))
-        return self.registry.render_prometheus()
+        # then the process's own series (``repro_*``: the worker-sharded
+        # engine's build-time gauges)
+        return (self.registry.render_prometheus()
+                + metrics_lib.process_registry().render_prometheus())
 
     # -------------------------------------------------------------- submit
     def submit(self, spec: grid_lib.SweepSpec,

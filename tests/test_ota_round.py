@@ -165,32 +165,35 @@ def test_fused_round_vmapped_batched_scalars_match_loop():
 def test_shard_tx_matches_block_ops(u_b):
     """``ota_shard_tx`` == the jnp block ops of the worker-sharded engine
     (eq.-44 beta from the rank-1 factorization, Algorithm-1 clipping,
-    the four partial reductions), also when the block is tiled over the
-    worker axis (u_b = 2500 takes three padded worker steps) and when
-    vmapped over experiments."""
+    the superposition added onto the carried y), also when the block is
+    tiled over the worker axis (u_b = 2500 takes two padded worker
+    steps), when D is not a multiple of the lane tile (a partial last
+    tile), and when vmapped over experiments."""
     from repro.core import power
 
     rng = np.random.default_rng(u_b)
     D = 3
 
-    def draw():
+    def draw(d=D):
         h = jnp.asarray(rng.exponential(size=u_b) + 1e-2, jnp.float32)
         return dict(
-            w=jnp.asarray(rng.normal(size=(u_b, D)), jnp.float32),
+            w=jnp.asarray(rng.normal(size=(u_b, d)), jnp.float32),
             h=h, h_est=h * jnp.asarray(rng.uniform(0.9, 1.1, u_b),
                                        jnp.float32),
             cw=jnp.asarray(rng.uniform(0.1, 2.0, u_b), jnp.float32),
-            s=jnp.asarray(rng.uniform(0.5, 2.0, D), jnp.float32),
-            b=jnp.asarray(rng.uniform(0.5, 1.5, D), jnp.float32),
+            s=jnp.asarray(rng.uniform(0.5, 2.0, d), jnp.float32),
+            b=jnp.asarray(rng.uniform(0.5, 1.5, d), jnp.float32),
             k_eff=jnp.asarray(rng.integers(5, 20, u_b), jnp.float32),
             k_i=jnp.asarray(rng.integers(5, 20, u_b), jnp.float32),
             p_max=jnp.asarray(rng.uniform(0.5, 10.0, u_b), jnp.float32),
-            wmask=jnp.asarray(rng.uniform(size=u_b) < 0.8, jnp.float32))
+            wmask=jnp.asarray(rng.uniform(size=u_b) < 0.8, jnp.float32),
+            y=jnp.asarray(rng.normal(size=d), jnp.float32))
 
-    def kernel(x):
+    def kernel(x, block_d=16384):
         return ops.ota_shard_tx(x["w"], x["h"], x["h_est"], x["cw"],
                                 x["s"], x["b"], x["k_eff"], x["k_i"],
-                                x["p_max"], x["wmask"], interpret=True)
+                                x["p_max"], x["wmask"], y=x["y"],
+                                block_d=block_d, interpret=True)
 
     def reference(x):
         beta = inflota_core.block_beta(x["b"], x["cw"], x["s"]) \
@@ -198,24 +201,23 @@ def test_shard_tx_matches_block_ops(u_b):
         y_terms = power.tx_signal(x["w"], beta, x["k_eff"], x["b"],
                                   x["h_est"][:, None], x["p_max"]) \
             * x["h"][:, None]
-        parts = (jnp.sum(y_terms, axis=0),
-                 jnp.sum(x["k_eff"][:, None] * beta, axis=0),
-                 jnp.sum(x["k_i"][:, None] * beta, axis=0),
-                 jnp.sum(beta, axis=0))
-        return parts, jnp.sum(jnp.abs(y_terms), axis=0)
+        return (x["y"] + jnp.sum(y_terms, axis=0),
+                jnp.abs(x["y"]) + jnp.sum(jnp.abs(y_terms), axis=0))
+
+    def close(got, x):
+        # y reassociates over the worker tiles: its error is bounded by
+        # the magnitude of its terms, not of the sum
+        want, l1 = reference(x)
+        assert np.all(np.abs(np.asarray(got) - np.asarray(want))
+                      <= 1e-6 * np.asarray(l1))
 
     xs = [draw(), draw()]
     batched = jax.vmap(kernel)(jax.tree.map(lambda *v: jnp.stack(v), *xs))
     for e, x in enumerate(xs):
-        want, l1 = reference(x)
-        for got in (kernel(x), [v[e] for v in batched]):
-            # y reassociates over the worker tiles: its error is bounded
-            # by the magnitude of its terms, not of the sum; the
-            # integer-valued beta reductions are exact
-            assert np.all(np.abs(np.asarray(got[0]) - np.asarray(want[0]))
-                          <= 1e-6 * np.asarray(l1))
-            for a, b in zip(got[1:], want[1:]):
-                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        for got in (kernel(x), batched[e]):
+            close(got, x)
+    wide = draw(300)
+    close(kernel(wide, block_d=128), wide)
 
 
 def test_search_kernel_rank1_equals_dense():

@@ -94,14 +94,14 @@ def test_inflota_search_vmapped_batched_scalars_compiles(one_chip):
     _compiled_kernel(jax.vmap(search), shapes, one_chip)
 
 
-def _shard_tx(w, h, h_est, cw, s, b, k_eff, k_i, p_max, wmask):
-    return ota_round.ota_shard_tx(w, h, h_est, cw, s, b, k_eff, k_i, p_max,
-                                  wmask, interpret=False)
+def _shard_tx(w, h, h_est, cw, s, b, k_eff, p_max, wmask, y):
+    return ota_round.ota_shard_tx(w, h, h_est, cw, s, b, k_eff, p_max,
+                                  wmask, y=y, interpret=False)
 
 
 def _shard_tx_shapes(u_b, D, lead=()):
     return [lead + s for s in ((u_b, D), (u_b,), (u_b,), (u_b,), (D,),
-                               (D,), (u_b,), (u_b,), (u_b,), (u_b,))]
+                               (D,), (u_b,), (u_b,), (u_b,), (D,))]
 
 
 @pytest.mark.parametrize("u_b", [1_000, 10_000])
@@ -110,6 +110,14 @@ def test_ota_shard_tx_compiles(one_chip, u_b, D):
     """A worker block of any size fits VMEM: blocks too large for one
     grid step are tiled over the worker axis."""
     _compiled_kernel(_shard_tx, _shard_tx_shapes(u_b, D), one_chip)
+
+
+@pytest.mark.parametrize("D", [512, 2048 * 11264, 20480 * 2048],
+                         ids=["norm", "dense_ffn", "embedding"])
+def test_ota_shard_tx_compiles_one_worker_a_block(one_chip, D):
+    """Moonlight-16B-A3B's leaves at one worker a block: lane tiles of
+    16,384 over tens of millions of entries."""
+    _compiled_kernel(_shard_tx, _shard_tx_shapes(1, D), one_chip)
 
 
 def test_ota_shard_tx_vmapped_compiles(one_chip):

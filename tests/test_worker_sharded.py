@@ -307,6 +307,64 @@ def test_u1e5_round_never_materializes_global_ud():
     walk(jaxpr.jaxpr)
     assert not offenders, f"(U, D)-sized intermediates traced: {offenders}"
 
+    # and no (S, D) stack either: at one worker a block of a wide model
+    # (the 784-64-10 MLP, D = 50,890, S = U = 4), every value that
+    # crosses blocks is a running (D,) value, under both backends
+    from repro.fl.models import mlp_model
+    U, K, S = 4, 10, 4
+    task = mlp_model()
+    X = jnp.zeros((U, K, 784), jnp.float32)
+    Y = jnp.zeros((U, K), jnp.int32)
+    mask = jnp.ones((U, K), jnp.float32)
+    k_i = jnp.full((U,), float(K), jnp.float32)
+    params0 = task.init(jax.random.PRNGKey(0))
+    flat0, _ = ravel_pytree(params0)
+    limit = S * flat0.shape[0]
+    for backend in ("jnp", "pallas"):
+        cfg = FLConfig(rounds=1, lr=0.05, policy="inflota", k_b=2,
+                       worker_sharding=S, backend=backend,
+                       constants=LearningConstants(sigma2=1e-4))
+        eng = build_engine(task, X, Y, mask, k_i, cfg, params0)
+        st = eng.init(flat0, jax.random.PRNGKey(0))
+        offenders = []
+        walk(jax.make_jaxpr(eng.step)(st).jaxpr)
+        assert not offenders, (
+            f"(S, D)-sized intermediates traced ({backend}): {offenders}")
+
+
+def test_running_envelope_matches_stacked_reduction():
+    """The engine's running first-min over blocks
+    (``inflota.envelope_step``) gives bit for bit the b, r and kstar of
+    the stacked ``reduce_envelopes``, ties included (duplicated
+    candidates in different blocks, equal curves across entries)."""
+    rng = np.random.default_rng(11)
+    S, u_b, D = 5, 4, 257
+    c = LearningConstants(sigma2=1e-3)
+    cw = rng.uniform(0.1, 2.0, (S, u_b)).astype(np.float32)
+    cw[3, 1] = cw[1, 2]                  # the same candidate twice
+    cw[4] = cw[0]                        # a whole block repeated
+    cw = jnp.asarray(cw)
+    k_den = jnp.asarray(rng.integers(5, 20, (S, u_b)), jnp.float32)
+    s = jnp.asarray(rng.uniform(0.2, 3.0, D), jnp.float32)
+    s = s.at[:64].set(s[0])              # equal curves across entries
+    numer = jnp.float32(37.0)
+    th, cs = jax.vmap(inflota.block_summary)(cw, k_den)
+    dens = [inflota.block_den(cw[j], th, cs) for j in range(S)]
+    stacked = [inflota.block_envelope(cw[j], dens[j], s, c, numer)
+               for j in range(S)]
+    b_ref, r_ref, k_ref = inflota.reduce_envelopes(
+        *[jnp.stack(v) for v in zip(*stacked)], s, u_b)
+    carry = (jnp.full((D,), jnp.inf, jnp.float32),
+             jnp.zeros((D,), jnp.int32))
+    for j in range(S):
+        carry = inflota.envelope_step(carry, cw[j], dens[j], s, c, numer,
+                                      j * u_b)
+    r, kstar = carry
+    np.testing.assert_array_equal(np.asarray(kstar), np.asarray(k_ref))
+    np.testing.assert_array_equal(np.asarray(r), np.asarray(r_ref))
+    np.testing.assert_array_equal(
+        np.asarray(jnp.take(cw.reshape(-1), kstar) * s), np.asarray(b_ref))
+
 
 # --------------------------------------------------- blessing of scaling
 
